@@ -44,6 +44,15 @@ PAPER_MODELS = {
     'sd_v1_4': SD_V1_4,
 }
 
+#: the VAE each latent paper model decodes through when served
+PAPER_VAES = {
+    'ldm_churches': VAE_256,
+    'sd_v1_4': VAE_512,
+}
+
+#: text-conditioning tokens per request (CLIP's context length for SD)
+CONTEXT_TOKENS = 77
+
 PAPER_PARAM_COUNTS = {          # Table I, millions
     'ddpm_cifar10': 61.9,
     'ldm_churches': 294.96,

@@ -116,36 +116,6 @@ class DiffusionPipeline:
             x = AE.vae_decode(self.vae_params, self.vae_cfg, x)
         return x
 
-    def _eps_fn(self, context=None, guidance: float = 0.0,
-                policy: Optional[PrecisionPolicy] = None, noise_key=None):
-        """Noise-prediction closure at a given precision.  For a noisy
-        policy the per-evaluation key folds in the (first) timestep so
-        the analog draw varies along the trajectory; an explicit
-        ``noise_key`` re-anchors it (the engine threads a per-tick key)."""
-        pol = resolve(policy) if policy is not None else self.policy
-        base = None
-        if pol.noisy:
-            base = noise_key if noise_key is not None else \
-                jax.random.PRNGKey(pol.noise_seed)
-
-        def keyed(t, branch):
-            if base is None:
-                return None
-            k = jax.random.fold_in(base, jnp.reshape(t, (-1,))[0])
-            return jax.random.fold_in(k, branch)
-
-        def eps(x, t):
-            e = U.unet_apply(self.unet_params, self.unet_cfg, x, t,
-                             context=context, policy=pol,
-                             noise_key=keyed(t, 0))
-            if guidance > 0.0 and context is not None:
-                e_unc = U.unet_apply(self.unet_params, self.unet_cfg, x, t,
-                                     context=None, policy=pol,
-                                     noise_key=keyed(t, 1))
-                e = e_unc + guidance * (e - e_unc)
-            return e
-        return eps
-
     def sample_shape(self, batch: int):
         c = self.unet_cfg
         return (batch, c.img_size, c.img_size, c.in_ch)
@@ -158,8 +128,9 @@ class DiffusionPipeline:
         (B,) vectors, so a batch may hold samples at different denoising
         depths (the serving engine's per-tick kernel).  ``policy``
         overrides the pipeline default for this step."""
-        eps = self._eps_fn(context, guidance, policy=policy,
-                           noise_key=noise_key)(x, jnp.asarray(t, jnp.int32))
+        pol = resolve(policy) if policy is not None else self.policy
+        eps = eps_fn(self.unet_cfg, self.unet_params, context, guidance,
+                     pol, noise_key)(x, jnp.asarray(t, jnp.int32))
         return samplers.ddim_step(self.sched, eps, x, t, t_prev)
 
     def generate(self, key, batch: int, steps: int = 50,
@@ -167,14 +138,58 @@ class DiffusionPipeline:
                  guidance: float = 0.0,
                  policy: Optional[PrecisionPolicy] = None) -> jax.Array:
         """Serve one batch of generation requests; returns images/latents.
-        ``policy`` overrides the pipeline's default precision."""
-        eps = self._eps_fn(context, guidance, policy=policy)
-        shape = self.sample_shape(batch)
-        if sampler == 'ddpm':
-            z = samplers.ddpm_sample(self.sched, eps, shape, key)
-        else:
-            z = samplers.ddim_sample(self.sched, eps, shape, key,
-                                     steps=steps)
-        if self.vae_params is not None:
-            z = AE.vae_decode(self.vae_params, self.vae_cfg, z)
-        return z
+        ``policy`` overrides the pipeline's default precision.  One jitted
+        program per (shapes, steps, sampler, guidance, policy) that takes
+        the UNet and VAE weights as arguments."""
+        pol = resolve(policy) if policy is not None else self.policy
+        return _generate(self.unet_params, self.vae_params, self.sched, key,
+                         context, unet_cfg=self.unet_cfg,
+                         vae_cfg=self.vae_cfg, batch=batch, steps=steps,
+                         sampler=sampler, guidance=float(guidance),
+                         policy=pol)
+
+
+def eps_fn(cfg: U.UNetConfig, params, context=None, guidance: float = 0.0,
+           policy: PrecisionPolicy = PrecisionPolicy.fp32(), noise_key=None):
+    """Noise-prediction closure ``eps(x, t)`` over explicit UNet
+    ``params``.  For a noisy policy the per-evaluation key folds in the
+    (first) timestep so the analog draw varies along the trajectory; an
+    explicit ``noise_key`` re-anchors it (the engine threads a per-tick
+    key).  ``guidance > 0`` with a ``context`` blends in the
+    unconditional prediction (classifier-free guidance)."""
+    base = None
+    if policy.noisy:
+        base = noise_key if noise_key is not None else \
+            jax.random.PRNGKey(policy.noise_seed)
+
+    def keyed(t, branch):
+        if base is None:
+            return None
+        k = jax.random.fold_in(base, jnp.reshape(t, (-1,))[0])
+        return jax.random.fold_in(k, branch)
+
+    def eps(x, t):
+        e = U.unet_apply(params, cfg, x, t, context=context, policy=policy,
+                         noise_key=keyed(t, 0))
+        if guidance > 0.0 and context is not None:
+            e_unc = U.unet_apply(params, cfg, x, t, context=None,
+                                 policy=policy, noise_key=keyed(t, 1))
+            e = e_unc + guidance * (e - e_unc)
+        return e
+    return eps
+
+
+@functools.partial(jax.jit, static_argnames=(
+    'unet_cfg', 'vae_cfg', 'batch', 'steps', 'sampler', 'guidance',
+    'policy'))
+def _generate(unet_params, vae_params, sched: Schedule, key, context, *,
+              unet_cfg, vae_cfg, batch, steps, sampler, guidance, policy):
+    eps = eps_fn(unet_cfg, unet_params, context, guidance, policy)
+    shape = (batch, unet_cfg.img_size, unet_cfg.img_size, unet_cfg.in_ch)
+    if sampler == 'ddpm':
+        z = samplers.ddpm_sample(sched, eps, shape, key)
+    else:
+        z = samplers.ddim_sample(sched, eps, shape, key, steps=steps)
+    if vae_params is not None:
+        z = AE.vae_decode(vae_params, vae_cfg, z)
+    return z

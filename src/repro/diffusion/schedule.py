@@ -2,12 +2,16 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=['betas', 'alphas', 'alpha_bars'],
+                   meta_fields=[])
 @dataclasses.dataclass(frozen=True)
 class Schedule:
     betas: jax.Array            # (T,)

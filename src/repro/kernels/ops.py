@@ -1,10 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handle padding to block multiples, activation quantization, head folding,
-and the CPU fallback: on a non-TPU backend the wrappers run the kernels in
-``interpret=True`` mode (bit-equivalent Python execution) or, when
-``REPRO_KERNELS=xla``, the pure-jnp oracle — the latter is what the
-distributed dry-run lowers so roofline terms reflect the XLA path.
+and the choice of path: on a TPU backend the wrappers run the Pallas
+kernels; on any other backend they run the pure-jnp oracles of
+``kernels/ref.py`` (``xla``).  ``REPRO_KERNELS`` forces one mode:
+``pallas``, ``xla``, or ``interpret`` (the kernels executed as Python on
+the host — what the kernel tests compare against the oracles).
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ from repro.kernels.w8a8_matmul import w8a8_matmul_kernel
 
 
 def _mode() -> str:
-    """'pallas' on TPU, 'interpret' on CPU, or forced via REPRO_KERNELS."""
+    """'pallas' on TPU, 'xla' elsewhere, or forced via REPRO_KERNELS."""
     forced = os.environ.get('REPRO_KERNELS')
     if forced:
         return forced

@@ -8,6 +8,12 @@ CPU-scale demos:
     PYTHONPATH=src python -m repro.launch.serve --diffusion \
         --requests 8 --rate 4 --slots 4 --steps 6 --precision w8a8
 
+A paper UNet at its published widths, with its VAE, seeded weights and a
+seeded text-conditioning stand-in (every other request guided at 7.5);
+this one needs an accelerator (``chip_smoke.py`` runs it on one TPU):
+    PYTHONPATH=src python -m repro.launch.serve --diffusion \
+        --model sd_v1_4 --requests 6 --slots 4 --steps 10
+
 The diffusion mode replays a Poisson arrival trace through the
 continuous-batching engine (``repro.serving``): requests arrive with
 exponential inter-arrival times at ``--rate`` req/s, are multiplexed
@@ -20,11 +26,13 @@ quality probe against the fp32 reference (the accuracy-vs-EPB frontier).
 
 Cold-start and overload hardening:
 
-``--cache-dir PATH`` routes every XLA compilation through JAX's
-persistent on-disk cache, so a restarted server *loads* its step
-variants instead of recompiling them — the warmup line reports the wall
-seconds and whether the cache was warm.  ``--overload X`` sizes the
-arrival rate at X times the engine's *measured* service capacity
+Every XLA compilation goes through JAX's persistent on-disk cache, so a
+restarted server *loads* its step variants instead of recompiling them —
+the warmup line reports the wall seconds and whether the cache was warm.
+The cache lives in ``$JAX_COMPILATION_CACHE_DIR`` when that is set, else
+in ``--cache-dir PATH``, else in ``.jax_cache`` at the checkout's root.
+``--overload X`` sizes the arrival rate at X times the engine's
+*measured* service capacity
 (``engine.measure_tick_s``), bounds the admission queue
 (``--queue-depth``, default 2x slots) and turns on deadline-aware
 shedding, then proves survival: the queue stays bounded, excess load is
@@ -32,8 +40,7 @@ shed (by cause), no deadline-dead request occupies a slot, and the
 p50/p99 queue waits are reported:
 
     PYTHONPATH=src python -m repro.launch.serve --diffusion \
-        --overload 5 --requests 32 --slots 4 --steps 6 \
-        --cache-dir /tmp/repro-xla-cache
+        --overload 5 --requests 32 --slots 4 --steps 6
 
 Sharded multi-device serving: ``--devices N`` builds a 1-D ``('data',)``
 mesh over the first N visible devices and shards the engine's slot axis
@@ -78,10 +85,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs.diffusion import CONTEXT_TOKENS
 from repro.configs.registry import get, smoke_config
 from repro.distributed import sharding as SH
 from repro.launch import steps as ST
 from repro.launch.mesh import make_mesh
+from repro.models.layers import count_params
 
 log_serve = logging.getLogger('serve')
 log_mesh = logging.getLogger('mesh')
@@ -143,19 +152,108 @@ def serve_lm(cfg, mesh, batch: int, prompt_len: int, new_tokens: int,
 
 
 def poisson_trace(n: int, rate_hz: float, steps: int, seed: int = 0,
-                  slo_ms=None, precision: str = 'fp32'):
-    """Poisson arrival trace: n requests, exponential inter-arrivals."""
+                  slo_ms=None, precision='fp32', guidance: float = 0.0):
+    """Poisson arrival trace: n requests, exponential inter-arrivals.
+    ``precision`` is one name, or a sequence cycled over request pairs;
+    ``guidance`` is the classifier-free guidance scale of every other
+    (odd-numbered) request.  With two precisions, four requests cover
+    every (precision, guided) pair."""
     from repro.serving import GenerationRequest
+    precisions = (precision,) if isinstance(precision, str) \
+        else tuple(precision)
     rng = np.random.default_rng(seed)
     arrivals = np.cumsum(rng.exponential(1.0 / rate_hz, n))
     return [GenerationRequest(request_id=i, seed=1000 + i, steps=steps,
                               arrival_time=float(a), slo_ms=slo_ms,
-                              precision=precision)
+                              precision=precisions[(i // 2) %
+                                                   len(precisions)],
+                              guidance=guidance if i % 2 else 0.0)
             for i, a in enumerate(arrivals)]
+
+
+#: the default UNet: a small model for CPU demos and tests
+TOY_MODEL = 'toy'
+
+#: classifier-free guidance scale of the guided requests of a
+#: text-conditioned model
+GUIDANCE = 7.5
+
+
+def build_pipeline(model: str = TOY_MODEL, img: int = 16, seed: int = 0):
+    """The diffusion pipeline ``serve --diffusion`` serves, with weights
+    drawn from ``seed`` (nothing is downloaded).  ``model`` is
+    ``'toy'`` (a small ``img``-pixel UNet) or a name in
+    ``configs.diffusion.PAPER_MODELS``, served at its published widths
+    with its VAE.  A paper model that does not trace is refused here,
+    before any weight is drawn."""
+    from repro.configs.diffusion import PAPER_MODELS, PAPER_VAES
+    from repro.diffusion.pipeline import DiffusionPipeline
+    from repro.models.unet import UNetConfig, init_unet, unet_apply
+    key = jax.random.PRNGKey(seed)
+    if model == TOY_MODEL:
+        cfg = UNetConfig('serve-diffusion', img_size=img, in_ch=3,
+                         base_ch=64, ch_mults=(1, 2), n_res_blocks=1,
+                         attn_resolutions=(img // 2,), n_heads=4,
+                         timesteps=100)
+        return DiffusionPipeline.init(key, cfg)
+    if model not in PAPER_MODELS:
+        raise ValueError(f'unknown model {model!r}: expected {TOY_MODEL!r} '
+                         f'or one of {sorted(PAPER_MODELS)}')
+    cfg = PAPER_MODELS[model]
+    S = jax.ShapeDtypeStruct
+    try:
+        params = jax.eval_shape(lambda k: init_unet(k, cfg), key)
+        ctx = None if cfg.context_dim is None else \
+            S((1, CONTEXT_TOKENS, cfg.context_dim), jnp.float32)
+        jax.eval_shape(
+            lambda p, x, t, c: unet_apply(p, cfg, x, t, c), params,
+            S((1, cfg.img_size, cfg.img_size, cfg.in_ch), jnp.float32),
+            S((1,), jnp.int32), ctx)
+    except Exception as e:
+        raise ValueError(f'model {model!r} cannot be served: its UNet does '
+                         f'not trace ({type(e).__name__}: {e})') from e
+    return DiffusionPipeline.init(key, cfg, PAPER_VAES.get(model))
+
+
+def build_context(pipe, slots: int, seed: int = 0):
+    """The engine-wide conditioning of a text-conditioned UNet: one seeded
+    ``(CONTEXT_TOKENS, context_dim)`` embedding standing in for a text
+    encoder's output, repeated over the ``slots`` rows.  None for an
+    unconditioned UNet."""
+    dim = pipe.unet_cfg.context_dim
+    if dim is None:
+        return None
+    row = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                            (1, CONTEXT_TOKENS, dim), jnp.float32)
+    return jnp.broadcast_to(row, (slots, CONTEXT_TOKENS, dim))
+
+
+def build_engine(model: str = TOY_MODEL, img: int = 16, slots: int = 4,
+                 seed: int = 0, devices=None, slots_per_device=None,
+                 pipe=None, **engine_kw):
+    """Pipeline, conditioning, mesh and continuous-batching engine, as
+    ``serve --diffusion`` sets them up.  ``devices`` shards the slot
+    axis over a 1-D mesh of the first N visible devices; ``pipe`` reuses
+    an already built pipeline (a second engine over the same weights);
+    ``engine_kw`` pass through to ``ContinuousBatchingEngine``."""
+    from repro.serving import ContinuousBatchingEngine
+    from repro.serving.batcher import align_slots
+    if pipe is None:
+        pipe = build_pipeline(model, img, seed)
+    mesh = None
+    if devices is not None:
+        from repro.launch.mesh import serving_mesh
+        mesh = serving_mesh(n_devices=devices)
+        slots = slots_per_device * devices if slots_per_device \
+            else align_slots(slots, devices)
+    return ContinuousBatchingEngine(
+        pipe, slots=slots, context=build_context(pipe, slots, seed),
+        mesh=mesh, slots_per_device=slots_per_device, **engine_kw)
 
 
 def serve_diffusion(img: int, steps: int, n_requests: int, rate_hz: float,
                     slots: int, precision: str = 'fp32', seed: int = 0,
+                    model: str = TOY_MODEL,
                     slo_ms=None, quality_probe: int = 1,
                     cache_interval: int = 1, exit_tol=None,
                     exit_patience: int = 2, cache_dir=None,
@@ -167,7 +265,9 @@ def serve_diffusion(img: int, steps: int, n_requests: int, rate_hz: float,
                     report_every=None):
     """Replay a Poisson arrival trace through the continuous-batching
     engine and print the serving + energy report, plus the per-policy
-    accuracy-vs-EPB frontier.  ``cache_interval > 1`` enables
+    accuracy-vs-EPB frontier.  ``model`` picks the UNet
+    (``build_pipeline``); a text-conditioned one guides every other
+    request at ``GUIDANCE``.  ``cache_interval > 1`` enables
     DeepCache-phased slotting (full UNet pass every ``cache_interval``
     ticks, shallow passes in between); ``exit_tol`` enables speculative
     early-exit draining once a request's x0 prediction stops moving.
@@ -189,18 +289,11 @@ def serve_diffusion(img: int, steps: int, n_requests: int, rate_hz: float,
     replay (reconciled against the metrics first); ``prom_path`` dumps
     the final Prometheus text exposition; ``report_every`` emits an
     in-run snapshot line every that-many seconds."""
-    from repro.diffusion.pipeline import DiffusionPipeline
-    from repro.models.unet import UNetConfig
     from repro.obs import (SnapshotReporter, Tracer, render_exposition,
                            write_chrome_trace, write_jsonl)
-    from repro.serving import (AdmissionQueue, ContinuousBatchingEngine,
-                               cache_entries, enable_persistent_cache,
-                               overload_factor)
+    from repro.serving import (AdmissionQueue, cache_entries,
+                               enable_persistent_cache, overload_factor)
 
-    cfg = UNetConfig('serve-diffusion', img_size=img, in_ch=3, base_ch=64,
-                     ch_mults=(1, 2), n_res_blocks=1,
-                     attn_resolutions=(img // 2,), n_heads=4, timesteps=100)
-    pipe = DiffusionPipeline.init(jax.random.PRNGKey(0), cfg)
     queue = None
     if overload > 0:
         queue_depth = 2 * slots if queue_depth is None else queue_depth
@@ -208,10 +301,6 @@ def serve_diffusion(img: int, steps: int, n_requests: int, rate_hz: float,
     if queue_depth is not None or shed_policy != 'reject-newest':
         queue = AdmissionQueue(max_depth=queue_depth,
                                shed_policy=shed_policy)
-    mesh = None
-    if devices is not None:
-        from repro.launch.mesh import serving_mesh
-        mesh = serving_mesh(n_devices=devices)
     tracer = Tracer() if (trace_path or log_json_path) else None
     reporter = None
     if report_every is not None and report_every > 0:
@@ -224,17 +313,21 @@ def serve_diffusion(img: int, steps: int, n_requests: int, rate_hz: float,
                          report.median_s * 1e3, report.threshold_s * 1e3,
                          report.recommendation)
 
-    engine = ContinuousBatchingEngine(pipe, slots=slots, queue=queue,
-                                      quality_probe=quality_probe,
-                                      cache_interval=cache_interval,
-                                      exit_tol=exit_tol,
-                                      exit_patience=exit_patience,
-                                      mesh=mesh,
-                                      slots_per_device=slots_per_device,
-                                      overlap_decode=overlap_decode,
-                                      tracer=tracer, reporter=reporter,
-                                      on_straggler=_on_straggler
-                                      if mesh is not None else None)
+    engine = build_engine(model, img, slots, devices=devices,
+                          slots_per_device=slots_per_device, queue=queue,
+                          quality_probe=quality_probe,
+                          cache_interval=cache_interval, exit_tol=exit_tol,
+                          exit_patience=exit_patience,
+                          overlap_decode=overlap_decode, tracer=tracer,
+                          reporter=reporter,
+                          on_straggler=_on_straggler
+                          if devices is not None else None)
+    mesh = engine.mesh
+    guidance = GUIDANCE if engine.context is not None else 0.0
+    log_serve.info('model %s: %.2fM UNet parameters%s', model,
+                   count_params(engine.pipe.unet_params) / 1e6,
+                   ', VAE decode' if engine.pipe.vae_params is not None
+                   else '')
     if mesh is not None:
         log_mesh.info('slot axis sharded over %d devices: %d slots '
                       '(%d/device), overlap_decode=%s', devices,
@@ -271,7 +364,7 @@ def serve_diffusion(img: int, steps: int, n_requests: int, rate_hz: float,
             overload_factor(rate_hz, tick_s, steps, slots), queue_depth,
             slo_ms, shed_policy)
     trace = poisson_trace(n_requests, rate_hz, steps, seed, slo_ms=slo_ms,
-                          precision=precision)
+                          precision=precision, guidance=guidance)
     sched = []
     if cache_interval > 1:
         sched.append(f'cache_interval={cache_interval}')
@@ -405,6 +498,11 @@ def main():
                          'fp32 reference (0 = off)')
     ap.add_argument('--diffusion', action='store_true',
                     help='serve diffusion requests (continuous batching)')
+    ap.add_argument('--model', default=TOY_MODEL,
+                    help=f'diffusion UNet: {TOY_MODEL!r} (a small --img-pixel '
+                         'demo model) or a paper model of '
+                         'configs/diffusion.py served at its published '
+                         'widths with its VAE, e.g. sd_v1_4')
     ap.add_argument('--requests', type=int, default=8)
     ap.add_argument('--rate', type=float, default=4.0,
                     help='Poisson arrival rate, req/s')
@@ -424,9 +522,11 @@ def main():
     ap.add_argument('--exit-patience', type=int, default=2,
                     help='consecutive converged ticks before early exit')
     ap.add_argument('--cache-dir', default=None,
-                    help='persistent XLA compilation cache directory: a '
+                    help='persistent XLA compilation cache directory (a '
                          'restarted server loads its compiled step '
-                         'variants from here instead of recompiling')
+                         'variants from here instead of recompiling); '
+                         '$JAX_COMPILATION_CACHE_DIR wins when set, and '
+                         'the default is .jax_cache in the checkout')
     ap.add_argument('--queue-depth', type=int, default=None,
                     help='bound the admission queue (default: unbounded; '
                          '--overload defaults this to 2x slots)')
@@ -481,13 +581,15 @@ def main():
     setup_logging(args.log_level)
     if args.diffusion:
         precision = args.precision or ('w8a8' if args.w8a8 else 'fp32')
+        from repro.serving.compile_cache import default_cache_dir
         serve_diffusion(args.img, args.steps, args.requests, args.rate,
-                        args.slots, precision=precision, slo_ms=args.slo_ms,
+                        args.slots, precision=precision, model=args.model,
+                        slo_ms=args.slo_ms,
                         quality_probe=args.quality_probe,
                         cache_interval=args.cache_interval,
                         exit_tol=args.exit_tol,
                         exit_patience=args.exit_patience,
-                        cache_dir=args.cache_dir,
+                        cache_dir=default_cache_dir(args.cache_dir),
                         queue_depth=args.queue_depth,
                         shed_policy=args.shed_policy,
                         overload=args.overload,

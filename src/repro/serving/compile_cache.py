@@ -15,11 +15,18 @@ fine) and idempotent.  The thresholds default to "cache everything":
 the CPU-scale demo UNets compile in well under JAX's default 1-second
 floor, which would silently skip them.
 
-Usage (the engine and ``launch/serve.py --cache-dir`` call this for
-you)::
+Where the cache lives: ``$JAX_COMPILATION_CACHE_DIR`` when that is set
+(then no other directory is ever configured), else the directory a caller
+passes, else ``DEFAULT_CACHE_DIR`` — ``.jax_cache`` at the checkout's
+root.  The path is part of each entry's key, so it is fixed: never a
+temporary directory, a PID or a timestamp.
 
-    from repro.serving.compile_cache import enable_persistent_cache
-    enable_persistent_cache('/var/cache/repro-xla')
+Usage (the engine, ``launch/serve.py`` and ``chip_smoke.py`` call this
+for you)::
+
+    from repro.serving.compile_cache import (default_cache_dir,
+                                             enable_persistent_cache)
+    enable_persistent_cache(default_cache_dir())
     engine.warmup(precisions=('fp32', 'w8a8'))   # cold: compiles + stores
     # ... restart the process ...
     engine.warmup(precisions=('fp32', 'w8a8'))   # warm: loads from disk
@@ -30,6 +37,14 @@ import os
 from typing import Optional
 
 import jax
+
+#: Environment variable naming the one cache directory, when set.
+ENV_VAR = 'JAX_COMPILATION_CACHE_DIR'
+
+#: The cache directory when neither the environment nor a caller names one.
+DEFAULT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), '..', '..', '..',
+    '.jax_cache'))
 
 #: The directory routed through ``enable_persistent_cache`` in this
 #: process, or None when the persistent cache is off.
@@ -52,13 +67,22 @@ _OPTIONAL_FLAGS = (
 )
 
 
+def default_cache_dir(cache_dir: Optional[str] = None) -> str:
+    """The cache directory to use: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set, else ``cache_dir``, else ``DEFAULT_CACHE_DIR``."""
+    return os.path.abspath(os.environ.get(ENV_VAR) or cache_dir
+                           or DEFAULT_CACHE_DIR)
+
+
 def enable_persistent_cache(cache_dir: str,
                             min_entry_size_bytes: int = -1,
                             min_compile_time_secs: float = 0.0,
                             max_bytes: Optional[int] = None) -> str:
     """Route every XLA compilation through a persistent on-disk cache.
 
-    Creates ``cache_dir`` if needed and returns its absolute path.
+    Creates the directory if needed and returns its absolute path.  With
+    ``$JAX_COMPILATION_CACHE_DIR`` set, that directory is used in place
+    of ``cache_dir``.
     ``min_entry_size_bytes=-1`` / ``min_compile_time_secs=0.0`` cache
     every executable regardless of size or compile time (JAX's defaults
     skip sub-second compiles, which covers every CPU-scale demo model).
@@ -70,20 +94,14 @@ def enable_persistent_cache(cache_dir: str,
     enforced now and after every engine warmup (``trim_cache``), evicting
     least-recently-used entries first.
     """
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
+    cache_dir = default_cache_dir(os.path.expanduser(cache_dir))
     os.makedirs(cache_dir, exist_ok=True)
     global _ACTIVE_DIR, _MAX_BYTES
-    try:
-        jax.config.update('jax_compilation_cache_dir', cache_dir)
-        jax.config.update('jax_persistent_cache_min_entry_size_bytes',
-                          min_entry_size_bytes)
-        jax.config.update('jax_persistent_cache_min_compile_time_secs',
-                          min_compile_time_secs)
-    except AttributeError:                         # pragma: no cover
-        # very old JAX: the experimental module is the only spelling
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc)
-        cc.set_cache_dir(cache_dir)
+    jax.config.update('jax_compilation_cache_dir', cache_dir)
+    jax.config.update('jax_persistent_cache_min_entry_size_bytes',
+                      min_entry_size_bytes)
+    jax.config.update('jax_persistent_cache_min_compile_time_secs',
+                      min_compile_time_secs)
     for flag, value in _OPTIONAL_FLAGS:
         try:
             jax.config.update(flag, value)
@@ -119,10 +137,7 @@ def disable_persistent_cache() -> None:
     """Turn the persistent cache off for subsequent compilations (tests
     use this to avoid leaking a temporary directory into later work)."""
     global _ACTIVE_DIR, _MAX_BYTES
-    try:
-        jax.config.update('jax_compilation_cache_dir', None)
-    except AttributeError:                         # pragma: no cover
-        pass
+    jax.config.update('jax_compilation_cache_dir', None)
     _reset_cache_state()
     _ACTIVE_DIR = None
     _MAX_BYTES = None

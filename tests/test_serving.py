@@ -386,3 +386,80 @@ def test_choose_slots_per_precision_mapping():
     # scalar step time broadcast over the mapping
     assert choose_slots({'fp32': 2.0, 'w8a8': 2.0}, 0.05, 10) == 3
     assert choose_slots({'fp32': 0.0}, 0.05, 10) == 1
+
+
+# ---------------------------------------------------------------------------
+# weights as arguments; serve's model setup
+# ---------------------------------------------------------------------------
+
+def test_steps_take_weights_as_arguments(pipe):
+    """No step variant or decode compiles a weight in as a constant: every
+    lowered program's constants are smaller than the model's weights."""
+    import re
+    vae = VAEConfig(img_size=16, in_ch=3, z_ch=3, base_ch=16,
+                    ch_mults=(1, 2), groups=8)
+    p = DiffusionPipeline(TINY, pipe.unet_params, pipe.sched, vae,
+                          jax.tree.map(jnp.asarray, DiffusionPipeline.init(
+                              jax.random.PRNGKey(1), TINY, vae).vae_params))
+    engine = ContinuousBatchingEngine(p, slots=2, quality_probe=0)
+    weights = jax.tree_util.tree_leaves((p.unet_params, p.vae_params))
+    smallest = min(int(w.size) for w in weights if w.ndim >= 2)
+    info = engine.aot_warmup(precisions=('fp32', 'w8a8'))
+    assert {'_step', '_step[w8a8]', '_decode'} <= set(info['compiled'])
+    for label, exe in info['compiled'].items():
+        for dims in re.findall(r'= f32\[([\d,]+)\]\S* constant\(',
+                               exe.as_text()):
+            n = int(np.prod([int(d) for d in dims.split(',')]))
+            assert n < smallest, (label, dims)
+
+
+def test_probe_reference_applies_conditioning_to_unguided_requests():
+    """An unguided step on a conditioned engine predicts the conditional
+    noise, so the probe's fp32 reference must be conditioned too."""
+    cfg = UNetConfig('tiny-sdm-probe', img_size=16, in_ch=3, base_ch=32,
+                     ch_mults=(1, 2), n_res_blocks=1, attn_resolutions=(8,),
+                     n_heads=4, timesteps=16, context_dim=8)
+    p = DiffusionPipeline.init(jax.random.PRNGKey(0), cfg)
+    ctx = jnp.tile(jax.random.normal(jax.random.PRNGKey(9), (1, 4, 8)),
+                   (2, 1, 1))
+    engine = ContinuousBatchingEngine(p, slots=2, context=ctx)
+    req = GenerationRequest(0, seed=21, steps=3, precision='w8a8')
+    results = _drive(engine, {0: [req]})
+    served_fp32 = p.generate(jax.random.PRNGKey(21), batch=1, steps=3,
+                             context=ctx[:1])
+    np.testing.assert_allclose(engine._fp32_reference(req, guided=False),
+                               np.asarray(served_fp32[0]), atol=1e-6)
+    assert results[0].quality_psnr_db > 40.0
+
+
+@pytest.mark.parametrize('model', ['ddpm_cifar10', 'ldm_churches',
+                                   'ldm_beds'])
+def test_serve_refuses_models_that_do_not_trace(model):
+    from repro.launch.serve import build_pipeline
+    with pytest.raises(ValueError, match='does not trace'):
+        build_pipeline(model)
+
+
+def test_serve_context_is_one_seeded_row_per_slot():
+    from repro.configs.diffusion import CONTEXT_TOKENS
+    from repro.launch.serve import build_context
+    cfg = UNetConfig('tiny-ctx', img_size=8, in_ch=4, base_ch=32,
+                     ch_mults=(1, 2), n_res_blocks=1, attn_resolutions=(4,),
+                     n_heads=4, timesteps=16, context_dim=8)
+    p = DiffusionPipeline.init(jax.random.PRNGKey(0), cfg)
+    ctx = np.asarray(build_context(p, slots=3, seed=5))
+    assert ctx.shape == (3, CONTEXT_TOKENS, 8)
+    np.testing.assert_array_equal(ctx[0], ctx[2])
+    np.testing.assert_array_equal(ctx, np.asarray(build_context(p, 3, 5)))
+    assert build_context(DiffusionPipeline.init(jax.random.PRNGKey(0), TINY),
+                         slots=3) is None
+
+
+def test_poisson_trace_covers_every_precision_and_guidance_pair():
+    from repro.launch.serve import poisson_trace
+    trace = poisson_trace(6, 10.0, 4, precision=('fp32', 'w8a8'),
+                          guidance=7.5)
+    pairs = [(r.precision, r.guidance) for r in trace]
+    assert pairs[:4] == [('fp32', 0.0), ('fp32', 7.5), ('w8a8', 0.0),
+                         ('w8a8', 7.5)]
+    assert all(r.precision == 'fp32' for r in poisson_trace(3, 10.0, 4))
