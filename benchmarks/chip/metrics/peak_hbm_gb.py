@@ -1,0 +1,5 @@
+"""``peak_bytes_in_use`` of the fullest chip after the window, in GB."""
+
+
+def read(run):
+    return run['peak_bytes'] / 1e9 if run['peak_bytes'] else None
