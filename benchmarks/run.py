@@ -649,8 +649,8 @@ def bench_overload(emit):
 def bench_obs_overhead(emit):
     """The observability tax: the SAME request batch served with tracing
     disabled (the zero-cost NULL_TRACER default) and enabled (a live
-    ``Tracer`` recording submit/slot-assign/step/tick/decode/request
-    events).  Hot paths guard on ``tracer.enabled``, so the traced run
+    ``Tracer`` recording the engine's submit/tick/admit/plan/dispatch/
+    drain spans and the submit/slot-assign/request events).  Hot paths guard on ``tracer.enabled``, so the traced run
     must stay within 5% of the untraced requests/s — asserted on the
     best-of-3 makespans per mode so scheduler noise cannot fail the
     gate.  Also reports the event volume one run records."""

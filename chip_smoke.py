@@ -156,7 +156,7 @@ def one_chip() -> None:
         log(f'[compile] {label}: {sec:.1f}s')
     log(f'[compile] {info["variants"]} programs in {info["seconds"]:.1f}s')
     for label, exe in info['compiled'].items():
-        if label.startswith('_step'):
+        if label.startswith('step_'):
             assert KERNEL_MARK in exe.as_text(), \
                 f'{label}: no Pallas kernel in the compiled step'
     log('[compile] every step variant holds a tpu_custom_call')

@@ -19,9 +19,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models import layers as L
-from repro.models.unet import (UNetConfig, attn_block, resblock,
-                               timestep_embedding, _gn_swish)
+from repro.models.unet import (UNetConfig, attn_block, conv_in, conv_out,
+                               downsample, resblock, t_embed, upsample)
 
 
 def unet_apply_cached(p, cfg: UNetConfig, x: jax.Array, t: jax.Array,
@@ -43,9 +42,8 @@ def unet_apply_cached(p, cfg: UNetConfig, x: jax.Array, t: jax.Array,
     pol = resolve(policy)
     keys = stream_for(pol, noise_key)
     g = cfg.groups
-    t_emb = timestep_embedding(t, cfg.base_ch)
-    t_emb = L.linear(p['t_mlp2'], L.swish(L.linear(p['t_mlp1'], t_emb)))
-    h = L.conv2d(p['conv_in'], x)
+    t_emb = t_embed(p, cfg, t)
+    h = conv_in(p, x)
     skips = [h]
     # --- outermost down level (always computed) ---
     lvl0 = p['down'][0]
@@ -60,7 +58,7 @@ def unet_apply_cached(p, cfg: UNetConfig, x: jax.Array, t: jax.Array,
         hh = h
         deep_skips = []
         if 'down' in lvl0:
-            hh = L.conv2d(lvl0['down'], hh, stride=2)
+            hh = downsample(lvl0['down'], hh)
             deep_skips.append(hh)
         for lvl_p in p['down'][1:]:
             for b in lvl_p['blocks']:
@@ -70,7 +68,7 @@ def unet_apply_cached(p, cfg: UNetConfig, x: jax.Array, t: jax.Array,
                                     pol, keys)
                 deep_skips.append(hh)
             if 'down' in lvl_p:
-                hh = L.conv2d(lvl_p['down'], hh, stride=2)
+                hh = downsample(lvl_p['down'], hh)
                 deep_skips.append(hh)
         hh = resblock(p['mid']['res1'], hh, t_emb, g)
         hh = attn_block(p['mid']['attn'], hh, g, cfg.n_heads, context, pol, keys)
@@ -83,8 +81,7 @@ def unet_apply_cached(p, cfg: UNetConfig, x: jax.Array, t: jax.Array,
                     hh = attn_block(b['attn'], hh, g, cfg.n_heads, context,
                                     pol, keys)
             if 'upconv' in lvl_p:
-                hh = L.conv_transpose2d(lvl_p['upconv'], hh, stride=2,
-                                        sparse_dataflow=cfg.sparse_dataflow)
+                hh = upsample(lvl_p['upconv'], hh, cfg)
         new_cache = hh                  # activation entering the last level
     else:
         new_cache = cache
@@ -97,8 +94,7 @@ def unet_apply_cached(p, cfg: UNetConfig, x: jax.Array, t: jax.Array,
         if 'attn' in b:
             h_up = attn_block(b['attn'], h_up, g, cfg.n_heads, context,
                               pol, keys)
-    h_up = _gn_swish(p['gn_out'], h_up, g)
-    return L.conv2d(p['conv_out'], h_up), new_cache
+    return conv_out(p, h_up, g), new_cache
 
 
 def shallow_workload_fraction(cfg: UNetConfig) -> float:

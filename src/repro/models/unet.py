@@ -8,6 +8,11 @@ sparsity-aware dataflow (C4).  A w8a8 ``PrecisionPolicy`` (see
 W8A8 path (C1), optionally with analog-noise injection — the serving
 configurations the paper evaluates.  The legacy ``quant=True`` flag is a
 deprecated alias for ``policy=PrecisionPolicy.w8a8()``.
+
+Every block runs under one ``jax.named_scope`` — ``t_embed``, ``conv_in``,
+``resblock``, ``attn`` (self- and cross-attention), ``downsample``,
+``upsample``, ``conv_out`` — and scopes never nest, so each compiled op's
+``op_name`` metadata names the one block it belongs to.
 """
 from __future__ import annotations
 
@@ -48,6 +53,36 @@ def timestep_embedding(t: jax.Array, dim: int) -> jax.Array:
     return jnp.concatenate([jnp.cos(ang), jnp.sin(ang)], axis=-1)
 
 
+def t_embed(p, cfg: UNetConfig, t: jax.Array) -> jax.Array:
+    """The timestep embedding and its MLP."""
+    with jax.named_scope('t_embed'):
+        t_emb = timestep_embedding(t, cfg.base_ch)
+        return L.linear(p['t_mlp2'], L.swish(L.linear(p['t_mlp1'], t_emb)))
+
+
+def conv_in(p, x: jax.Array) -> jax.Array:
+    with jax.named_scope('conv_in'):
+        return L.conv2d(p['conv_in'], x)
+
+
+def downsample(w, h: jax.Array) -> jax.Array:
+    with jax.named_scope('downsample'):
+        return L.conv2d(w, h, stride=2)
+
+
+def upsample(w, h: jax.Array, cfg: UNetConfig) -> jax.Array:
+    """Stride-2 transposed conv (the C4 sparse-dataflow target)."""
+    with jax.named_scope('upsample'):
+        return L.conv_transpose2d(w, h, stride=2,
+                                  sparse_dataflow=cfg.sparse_dataflow)
+
+
+def conv_out(p, h: jax.Array, groups: int) -> jax.Array:
+    """The output GroupNorm+swish and convolution."""
+    with jax.named_scope('conv_out'):
+        return L.conv2d(p['conv_out'], _gn_swish(p['gn_out'], h, groups))
+
+
 # ---------------------------------------------------------------------------
 # blocks
 # ---------------------------------------------------------------------------
@@ -72,13 +107,14 @@ def _gn_swish(gn_p, x, groups):
 
 
 def resblock(p, x: jax.Array, t_emb: jax.Array, groups: int) -> jax.Array:
-    h = _gn_swish(p['gn1'], x, groups)
-    h = L.conv2d(p['conv1'], h)
-    h = h + L.linear(p['t_proj'], L.swish(t_emb))[:, None, None, :]
-    h = _gn_swish(p['gn2'], h, groups)
-    h = L.conv2d(p['conv2'], h)
-    skip = L.conv2d(p['skip'], x) if 'skip' in p else x
-    return skip + h
+    with jax.named_scope('resblock'):
+        h = _gn_swish(p['gn1'], x, groups)
+        h = L.conv2d(p['conv1'], h)
+        h = h + L.linear(p['t_proj'], L.swish(t_emb))[:, None, None, :]
+        h = _gn_swish(p['gn2'], h, groups)
+        h = L.conv2d(p['conv2'], h)
+        skip = L.conv2d(p['skip'], x) if 'skip' in p else x
+        return skip + h
 
 
 def init_attn_block(key, ch: int, n_heads: int,
@@ -128,19 +164,21 @@ def attn_block(p, x: jax.Array, groups: int, n_heads: int,
     if keys is None:
         keys = stream_for(pol)
     B, H, W, C = x.shape
-    h = L.groupnorm(p['gn'], x, groups)
-    t = h.reshape(B, H * W, C)
 
     def proj(q, v):
         return L.linear(q, v, policy=pol, noise_key=keys.next())
 
-    o = _mha(proj(p['wq'], t), proj(p['wk'], t), proj(p['wv'], t), n_heads)
-    t = t + proj(p['wo'], o)
-    if context is not None and 'xq' in p:
-        o = _mha(proj(p['xq'], t), proj(p['xk'], context),
-                 proj(p['xv'], context), n_heads)
-        t = t + proj(p['xo'], o)
-    return x + t.reshape(B, H, W, C)
+    with jax.named_scope('attn'):
+        h = L.groupnorm(p['gn'], x, groups)
+        t = h.reshape(B, H * W, C)
+        o = _mha(proj(p['wq'], t), proj(p['wk'], t), proj(p['wv'], t),
+                 n_heads)
+        t = t + proj(p['wo'], o)
+        if context is not None and 'xq' in p:
+            o = _mha(proj(p['xq'], t), proj(p['xk'], context),
+                     proj(p['xv'], context), n_heads)
+            t = t + proj(p['xo'], o)
+        return x + t.reshape(B, H, W, C)
 
 
 # ---------------------------------------------------------------------------
@@ -222,9 +260,8 @@ def unet_apply(p, cfg: UNetConfig, x: jax.Array, t: jax.Array,
     pol = resolve(policy, quant)
     keys = stream_for(pol, noise_key)
     g = cfg.groups
-    t_emb = timestep_embedding(t, cfg.base_ch)
-    t_emb = L.linear(p['t_mlp2'], L.swish(L.linear(p['t_mlp1'], t_emb)))
-    h = L.conv2d(p['conv_in'], x)
+    t_emb = t_embed(p, cfg, t)
+    h = conv_in(p, x)
     skips = [h]
     for lvl, lvl_p in enumerate(p['down']):
         for b in lvl_p['blocks']:
@@ -233,7 +270,7 @@ def unet_apply(p, cfg: UNetConfig, x: jax.Array, t: jax.Array,
                 h = attn_block(b['attn'], h, g, cfg.n_heads, context, pol, keys)
             skips.append(h)
         if 'down' in lvl_p:
-            h = L.conv2d(lvl_p['down'], h, stride=2)
+            h = downsample(lvl_p['down'], h)
             skips.append(h)
     h = resblock(p['mid']['res1'], h, t_emb, g)
     h = attn_block(p['mid']['attn'], h, g, cfg.n_heads, context, pol, keys)
@@ -245,7 +282,5 @@ def unet_apply(p, cfg: UNetConfig, x: jax.Array, t: jax.Array,
             if 'attn' in b:
                 h = attn_block(b['attn'], h, g, cfg.n_heads, context, pol, keys)
         if 'upconv' in lvl_p:
-            h = L.conv_transpose2d(lvl_p['upconv'], h, stride=2,
-                                   sparse_dataflow=cfg.sparse_dataflow)
-    h = _gn_swish(p['gn_out'], h, g)
-    return L.conv2d(p['conv_out'], h)
+            h = upsample(lvl_p['upconv'], h, cfg)
+    return conv_out(p, h, g)

@@ -2,8 +2,9 @@
 
 One ``Tracer`` records a serving run as a flat list of ``TraceEvent``
 rows — instants (a request was submitted, a shed happened, a straggler
-was flagged), complete spans (a step dispatch, a whole tick, warmup, a
-request's submit-to-finish lifetime) and counters (occupancy per tick).
+was flagged), complete spans (a whole tick, a step dispatch, a drained
+image, warmup, a request's submit-to-finish lifetime) and counters
+(occupancy per tick).
 Timestamps ride the *serving clock*: ``now()`` is monotonic seconds
 since the tracer's origin (``time.perf_counter`` based), and
 ``set_origin`` lets the engine pin that origin to its replay wall-clock
@@ -12,23 +13,43 @@ exactly.  Events recorded with an explicit ``ts`` (e.g. a request span
 stamped from the result's own submit/finish times) reconcile with
 ``ServingMetrics`` by construction.
 
-Tracing is ZERO-COST when disabled: the default engine tracer is the
+``region`` is the one span primitive.  Every span, traced or not, is
+also a ``jax.profiler.TraceAnnotation`` named ``<cat>.<name>`` (e.g.
+``engine.drain``) carrying the span's int and string arguments as
+metadata, so whenever a profiler is running the span lands on the
+``/host:CPU`` plane of the same trace as the device ops, on their clock.
+With no profiler running a span costs a few microseconds of host time.
+
+Recording is ZERO-COST when disabled: the default engine tracer is the
 module singleton ``NULL_TRACER`` whose ``enabled`` flag is False — hot
-paths guard on that flag and never build event objects, and every
-recording method is a no-op.  An enabled tracer appends one small
-dataclass per event; exporters (``repro.obs.export``) turn the list into
-a JSONL structured log or a Chrome/Perfetto ``trace_event`` timeline.
+paths guard on that flag and never build event objects, every recording
+method is a no-op, and its spans are profiler annotations alone.  An
+enabled tracer appends one small dataclass per event; exporters
+(``repro.obs.export``) turn the list into a JSONL structured log or a
+Chrome/Perfetto ``trace_event`` timeline.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 #: Event categories used by the serving instrumentation.  Free-form —
 #: exporters pass them through — but the engine sticks to this set.
-CATEGORIES = ('queue', 'request', 'tick', 'decode', 'engine')
+CATEGORIES = ('queue', 'request', 'engine')
+
+#: profiler names of spans, ``(cat, name) -> '<cat>.<name>'``, built once
+#: per pair so a span formats no text
+_LABELS: Dict[Tuple[str, str], str] = {}
+
+
+def _meta(args: Dict[str, Any]) -> Dict[str, Any]:
+    """The arguments a profiler annotation carries: ints (bools as 0/1)
+    and strings."""
+    return {k: int(v) if type(v) is bool else v for k, v in args.items()
+            if type(v) in (int, str, bool)}
 
 
 @dataclasses.dataclass
@@ -115,15 +136,11 @@ class Tracer:
         self.events.append(e)
         return e
 
-    @contextlib.contextmanager
-    def region(self, name: str, cat: str = 'engine',
-               **args) -> Iterator[None]:
-        """Span context manager on the tracer clock."""
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.complete(name, t0, self.now(), cat=cat, **args)
+    def region(self, name: str, cat: str = 'engine', **args) -> 'Span':
+        """Span context manager: a profiler annotation ``<cat>.<name>``
+        and, when this tracer records, a complete event on its clock.
+        ``set`` on the span adds arguments known only at its end."""
+        return Span(self, name, cat, args)
 
     # -- reading ------------------------------------------------------------
     def __len__(self) -> int:
@@ -143,10 +160,45 @@ class Tracer:
         return self.select(name=name, cat=cat, ph='X')
 
 
+class Span:
+    """One ``Tracer.region``: entered, it opens the profiler annotation
+    and reads the tracer clock; exited, it records the complete event
+    (enabled tracers only) and closes the annotation."""
+
+    __slots__ = ('_tracer', '_ann', '_t0', 'name', 'cat', 'args')
+
+    def __init__(self, tracer: Tracer, name: str, cat: str,
+                 args: Dict[str, Any]):
+        label = _LABELS.get((cat, name))
+        if label is None:
+            label = _LABELS.setdefault((cat, name), cat + '.' + name)
+        self._tracer, self.name, self.cat, self.args = tracer, name, cat, args
+        self._ann = TraceAnnotation(label, **_meta(args))
+        self._t0 = 0.0
+
+    def __enter__(self) -> 'Span':
+        self._ann.__enter__()
+        if self._tracer.enabled:
+            self._t0 = self._tracer.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._tracer.enabled:
+            self._tracer.complete(self.name, self._t0, self._tracer.now(),
+                                  cat=self.cat, **self.args)
+        self._ann.__exit__(*exc)
+
+    def set(self, **args) -> None:
+        """Arguments known only once the span's work is done."""
+        self._ann.set_metadata(**_meta(args))
+        self.args.update(args)
+
+
 class NullTracer(Tracer):
     """No-op tracer: the zero-cost default.  ``enabled`` is False, so
     instrumented hot paths skip event construction entirely; the
-    recording methods are inert for call sites that don't guard."""
+    recording methods are inert for call sites that don't guard, and
+    its regions are profiler annotations alone."""
 
     enabled = False
 
@@ -161,10 +213,6 @@ class NullTracer(Tracer):
 
     def counter(self, *a, **k) -> None:          # type: ignore[override]
         return None
-
-    @contextlib.contextmanager
-    def region(self, *a, **k) -> Iterator[None]:
-        yield
 
 
 #: Shared no-op singleton — the engine's default ``tracer``.

@@ -116,17 +116,30 @@ policy=...)`` on its own (tests pin this at atol 1e-5).  ``w8a8+noise``
 is deterministic under the engine's noise seed: two engines with the same
 seed and request sequence produce identical images.
 
-Observability (``repro.obs``): construct with ``tracer=Tracer()`` and
-the engine records every request's lifecycle — submit, shed (with the
-specific victim, via the queue's ``on_shed`` hook), slot assignment,
-one span per step dispatch tagged (precision, refresh|skip, guided)
-with its PhotonicAccountant energy delta, early exit, decode dispatch /
-overlapped completion, and a submit-to-finish request span stamped from
-the SAME timing fields the metrics use (so trace and metrics reconcile
-exactly) — plus engine-global events (warmup, AOT lowering, elastic
-resize, straggler flags) and a per-tick occupancy counter.  The default
-is the no-op ``NULL_TRACER``; every hot-path hook guards on
-``tracer.enabled``, so an untraced engine builds no event objects.
+Observability (``repro.obs``).  The engine's phases are spans
+(``Tracer.region``), named as a ``jax.profiler`` trace shows them:
+``engine.submit``; ``engine.tick`` around the whole tick, and inside it
+``engine.admit`` (expiry, unpark, pops, each new request's noise and slot
+writes), ``engine.plan`` (the per-slot arrays and their copies to the
+device), one ``engine.dispatch`` per step call (tagged tick, precision,
+guided, refresh, slots), ``engine.exit_sync`` (the early-exit read, when
+it runs) and one ``engine.drain`` per drained image (its take, decode
+dispatch, host copy and bookkeeping; under decode overlap, the flush that
+materializes it).  Every span is a profiler annotation whether or not
+the engine records, so any ``jax.profiler`` trace of a serving process
+puts each host phase on the device ops' clock.  Every device program has
+a stable name: ``jit_step_<precision>[_refresh|_skip][_guided]``,
+``jit_init_noise``, ``jit_place_row``, ``jit_take_row`` and
+``jit_vae_decode``.  Construct with ``tracer=Tracer()`` and the engine
+also records the spans on the serving clock, with every request's
+lifecycle — submit, shed (with the specific victim, via the queue's
+``on_shed`` hook), slot assignment, early exit, and a submit-to-finish
+request span stamped from the SAME timing fields the metrics use (so
+trace and metrics reconcile exactly) — plus engine-global events
+(warmup, AOT lowering, elastic resize, straggler flags) and a per-tick
+occupancy counter.  The default is the no-op ``NULL_TRACER``; every
+hot-path event guards on ``tracer.enabled``, so an untraced engine
+builds no event objects and its spans are annotations alone.
 ``on_straggler=`` registers a callback the ``StepMonitor`` fires when
 its flagged-device set changes — the hook a deployment uses to trigger
 ``elastic_resize`` from measured straggle instead of a fixed schedule.
@@ -368,26 +381,36 @@ class ContinuousBatchingEngine:
                 self._ctx = jax.device_put(self._ctx, self._shard)
 
     def _build_helpers(self) -> None:
-        """(Re)build the fixed-shape jitted helpers.  ``_place`` pins its
-        output to the slot sharding so single-sample writes never gather
-        the buffer onto one device.  Called at construction and again by
+        """(Re)build the fixed-shape jitted helpers, each named so that
+        it runs as ``jit_<name>``.  ``_place`` pins its output to the
+        slot sharding so single-sample writes never gather the buffer
+        onto one device.  Called at construction and again by
         ``elastic_resize`` — ``out_shardings`` captures the mesh, so a
         topology change must re-create the wrapped functions."""
         pipe = self.pipe
-        # initial noise exactly as ddim_sample: x = normal(split(key)[0], .)
-        self._init_noise = jax.jit(lambda key: jax.random.normal(
-            jax.random.split(key)[0], (1,) + self._sample_shape)[0])
+        shape = (1,) + self._sample_shape
+
+        def init_noise(key):
+            # exactly as ddim_sample: x = normal(split(key)[0], .)
+            return jax.random.normal(jax.random.split(key)[0], shape)[0]
+
+        def place_row(x, i, v):
+            return x.at[i].set(v)
+
+        def take_row(x, i):
+            return x[i]
+
+        def vae_decode(vp, z):
+            return AE.vae_decode(vp, pipe.vae_cfg, z)
+
+        self._init_noise = jax.jit(init_noise)
         if self._shard is not None:
-            self._place = jax.jit(lambda x, i, v: x.at[i].set(v),
-                                  out_shardings=self._shard)
+            self._place = jax.jit(place_row, out_shardings=self._shard)
         else:
-            self._place = jax.jit(lambda x, i, v: x.at[i].set(v))
-        self._take = jax.jit(lambda x, i: x[i])
-        if pipe.vae_params is not None:
-            self._decode = jax.jit(lambda vp, z: AE.vae_decode(
-                vp, pipe.vae_cfg, z))
-        else:
-            self._decode = None
+            self._place = jax.jit(place_row)
+        self._take = jax.jit(take_row)
+        self._decode = jax.jit(vae_decode) \
+            if pipe.vae_params is not None else None
 
     # -- precision machinery ------------------------------------------------
     def _policy_for(self, name: str) -> PrecisionPolicy:
@@ -484,15 +507,17 @@ class ContinuousBatchingEngine:
                 return x_out, x0_out, delta, cache_c
         return step
 
-    def _per_device(self, step, n_rows: int, n_out: int):
+    def _per_device(self, step, n_rows: int, n_out: int, name: str):
         """Jit a step whose first ``n_rows`` arguments and every output
         are slot-axis buffers, followed by (t, t_prev, active, guidance,
-        key, params, ctx).  On a mesh the step runs once per device over
-        that device's slot rows (``shard_map``): Pallas kernels cannot be
-        partitioned automatically, and rows are independent, so no
-        collective is needed.  Weights and the key are replicated; the
-        conditioning is split by slot like the latents.  Slot buffers are
-        donated, so they stay resident across ticks."""
+        key, params, ctx), as the program ``jit_<name>``.  On a mesh the
+        step runs once per device over that device's slot rows
+        (``shard_map``): Pallas kernels cannot be partitioned
+        automatically, and rows are independent, so no collective is
+        needed.  Weights and the key are replicated; the conditioning is
+        split by slot like the latents.  Slot buffers are donated, so they
+        stay resident across ticks."""
+        step.__name__ = step.__qualname__ = name
         donate = tuple(range(n_rows))
         if self.mesh is None:
             return jax.jit(step, donate_argnums=donate)
@@ -508,8 +533,9 @@ class ContinuousBatchingEngine:
         k = (precision, guided)
         if k not in self._steps:
             pol = self._policy_for(precision)
-            self._steps[k] = self._per_device(self._make_step(pol, guided),
-                                              2, 3)
+            self._steps[k] = self._per_device(
+                self._make_step(pol, guided), 2, 3,
+                self.step_label(precision, guided))
         return self._steps[k]
 
     def _get_cached_step(self, precision: str, guided: bool, refresh: bool):
@@ -519,7 +545,7 @@ class ContinuousBatchingEngine:
             n_rows = 4 if guided else 3
             self._csteps[k] = self._per_device(
                 self._make_cached_step(pol, guided, refresh), n_rows,
-                n_rows + 1)
+                n_rows + 1, self.step_label(precision, guided, refresh))
         return self._csteps[k]
 
     def _tick_key(self, pol: PrecisionPolicy, tick_idx: int):
@@ -564,34 +590,34 @@ class ContinuousBatchingEngine:
         return req.steps * self._tick_s
 
     def compile_stats(self) -> Dict[str, int]:
-        """Per-jitted-function compile counts (cache sizes).  Constant
-        after one warmup per served policy == zero recompilation.  Step
-        entries are labeled ``_step`` / ``_step_guided`` for fp32 and
-        ``_step[w8a8]``-style for quantized policies; the DeepCache pair
-        appears as ``_step_refresh`` / ``_step_skip`` variants."""
+        """Per-jitted-function compile counts (cache sizes), keyed by
+        program name (``step_label`` for the step variants, e.g.
+        ``step_fp32_guided`` or ``step_w8a8_refresh``; ``init_noise``,
+        ``place_row``, ``take_row``, ``vae_decode`` for the helpers).
+        Constant after one warmup per served policy == zero
+        recompilation."""
         out = {}
         for (pname, guided), fn in self._steps.items():
             out[self.step_label(pname, guided)] = self._cache_size(fn)
         for (pname, guided, refresh), fn in self._csteps.items():
             out[self.step_label(pname, guided, refresh)] = \
                 self._cache_size(fn)
-        for name in ('_init_noise', '_place', '_take', '_decode'):
-            fn = getattr(self, name)
-            if fn is None:
-                continue
-            out[name] = self._cache_size(fn)
+        for fn in (self._init_noise, self._place, self._take, self._decode):
+            if fn is not None:
+                out[fn.__name__] = self._cache_size(fn)
         return out
 
     @staticmethod
     def step_label(precision: str, guided: bool,
                    refresh: Optional[bool] = None) -> str:
-        """The ``compile_stats`` / ``aot_warmup`` name of a step variant."""
-        if refresh is None:
-            base = '_step_guided' if guided else '_step'
-        else:
-            base = ('_step_refresh' if refresh else '_step_skip') + (
-                '_guided' if guided else '')
-        return base + ('' if precision == 'fp32' else f'[{precision}]')
+        """The name of a step variant, e.g. ``step_fp32_guided`` or
+        ``step_w8a8_noise_skip``: it runs as the program ``jit_<name>``
+        (every step program, and no other, starts with ``jit_step``) and
+        keys ``compile_stats`` and ``aot_warmup``."""
+        name = 'step_' + precision.replace('+', '_')
+        if refresh is not None:
+            name += '_refresh' if refresh else '_skip'
+        return name + ('_guided' if guided else '')
 
     @staticmethod
     def _cache_size(fn) -> int:
@@ -625,18 +651,6 @@ class ContinuousBatchingEngine:
             return None
         return idx // self._slots_per_device
 
-    def _step_energy_j(self, precision: str, refresh: bool,
-                       guided: bool) -> float:
-        """Energy one slot consumes in one tick at (precision, refresh
-        kind) — the per-event delta step trace events carry.  Rides the
-        accountant's simulation cache, so per-tick cost is a dict hit."""
-        if self.photonic is None:
-            return 0.0
-        full, cached = (1, 0) if refresh else (0, 1)
-        energy_j, _ = self.photonic.energy_evals(full, cached, guided,
-                                                 precision=precision)
-        return energy_j
-
     def _poll_straggler(self):
         """Check the ``StepMonitor`` and, when its flagged-device set
         CHANGES, emit a straggler trace event and fire ``on_straggler``
@@ -661,18 +675,19 @@ class ContinuousBatchingEngine:
     def submit(self, req: GenerationRequest,
                now: Optional[float] = None) -> bool:
         now = time.perf_counter() if now is None else now
-        # sheds (rejected arrival / evicted entry) are recorded by the
-        # queue's on_shed hook with the specific victim request
-        ok = self.queue.submit(req, now)
-        if ok:
-            self.metrics.record_submit(now)
-            if self.tracer.enabled:
-                self.tracer.instant('submit', cat='queue', ts=now,
-                                    rid=req.request_id,
-                                    steps=req.steps,
-                                    precision=req.precision,
-                                    trace_id=req.effective_trace_id)
-        self.metrics.observe_queue_depth(len(self.queue))
+        with self.tracer.region('submit', rid=req.request_id):
+            # sheds (rejected arrival / evicted entry) are recorded by the
+            # queue's on_shed hook with the specific victim request
+            ok = self.queue.submit(req, now)
+            if ok:
+                self.metrics.record_submit(now)
+                if self.tracer.enabled:
+                    self.tracer.instant('submit', cat='queue', ts=now,
+                                        rid=req.request_id,
+                                        steps=req.steps,
+                                        precision=req.precision,
+                                        trace_id=req.effective_trace_id)
+            self.metrics.observe_queue_depth(len(self.queue))
         return ok
 
     def _trajectory(self, steps: int) -> np.ndarray:
@@ -703,7 +718,9 @@ class ContinuousBatchingEngine:
                                 device=self._slot_device(idx),
                                 step_index=a.i)
 
-    def _admit(self, now: float) -> None:
+    def _admit(self, now: float) -> int:
+        """Fill free slots, parked requests first; returns how many
+        requests entered a slot."""
         # expire whenever ANY queued entry carries a deadline — the SLO
         # is a property of the request, not of the shed policy, so a
         # dead request must never occupy a slot under 'reject-newest' or
@@ -715,11 +732,13 @@ class ContinuousBatchingEngine:
             self.queue.expire(now, margin_s=self._service_margin_s)
         # parked (resize-displaced) requests re-enter ahead of the queue;
         # force_refresh lets them rejoin mid-cadence (a mixed tick)
+        admitted = 0
         for idx in range(self.slots):
             if not self._parked:
                 break
             if self._slot[idx] is None:
                 self._unpark(idx)
+                admitted += 1
         if self.cache_interval > 1:
             if self._cached_active() == 0:
                 # nothing riding the cadence: re-anchor it so admission
@@ -729,13 +748,14 @@ class ContinuousBatchingEngine:
                 # phase-aligned admission: hold queued requests until the
                 # next refresh tick so every skip tick stays a whole-batch
                 # shallow pass (the phase-alignment invariant)
-                return
+                return admitted
         for idx in range(self.slots):
             if self._slot[idx] is not None:
                 continue
             q = self.queue.pop()
             if q is None:
-                return
+                break
+            admitted += 1
             req = q.request
             interval = self.cache_interval if req.cache_interval is None \
                 else req.cache_interval
@@ -758,6 +778,7 @@ class ContinuousBatchingEngine:
             # seed the x0 tracker with the slot's noise: the first delta
             # is meaningless and ignored (exit_min_steps >= 2)
             self.x0 = self._place(self.x0, jnp.int32(idx), noise)
+        return admitted
 
     def _fp32_reference(self, req: GenerationRequest,
                         guided: bool) -> np.ndarray:
@@ -795,24 +816,18 @@ class ContinuousBatchingEngine:
         if self._decode is not None:
             z = self._decode(self._vae_w, z)
         self._slot[idx] = None
-        if self.tracer.enabled:
-            if early:
-                self.tracer.instant('early_exit', cat='request', ts=now,
-                                    rid=a.request.request_id, slot=idx,
-                                    device=self._slot_device(idx),
-                                    steps_executed=a.i,
-                                    steps_requested=a.request.steps)
-            self.tracer.instant('decode_dispatch', cat='decode', ts=now,
+        if early and self.tracer.enabled:
+            self.tracer.instant('early_exit', cat='request', ts=now,
                                 rid=a.request.request_id, slot=idx,
-                                device=self._slot_device(idx))
+                                device=self._slot_device(idx),
+                                steps_executed=a.i,
+                                steps_requested=a.request.steps)
         return _Pending(active=a, z=z, now=now, wall_clock=wall_clock,
                         early=early, slot=idx)
 
-    def _finish_drain(self, p: _Pending,
-                      overlapped: bool = False) -> GenerationResult:
+    def _finish_drain(self, p: _Pending) -> GenerationResult:
         """Materialize a dispatched drain: device sync, latency stamp,
-        energy + quality accounting, completion metrics.  ``overlapped``
-        marks a decode that hid behind the following tick's UNet step."""
+        energy + quality accounting, completion metrics."""
         a, z, now, wall_clock, early = (p.active, p.z, p.now,
                                         p.wall_clock, p.early)
         req = a.request
@@ -854,10 +869,6 @@ class ContinuousBatchingEngine:
             trace_id=req.effective_trace_id)
         self.metrics.record_complete(res, slo_ms=req.slo_ms)
         if self.tracer.enabled:
-            self.tracer.instant('decode_done', cat='decode', ts=now,
-                                rid=req.request_id, slot=p.slot,
-                                device=self._slot_device(p.slot),
-                                overlapped=overlapped)
             # the request span is stamped from the RESULT's own timing
             # fields, so trace latency == metrics latency exactly
             self.tracer.complete(
@@ -869,9 +880,6 @@ class ContinuousBatchingEngine:
                 cached_evals=a.cached_evals, early_exit=early,
                 queue_wait_s=res.queue_delay_s, energy_j=energy_j,
                 slo_ms=req.slo_ms)
-            self.tracer.instant('complete', cat='request', ts=now,
-                                rid=req.request_id, slot=p.slot,
-                                latency_s=res.latency_s)
         return res
 
     def _flush_pending(self, overlapped: bool) -> List[GenerationResult]:
@@ -883,8 +891,12 @@ class ContinuousBatchingEngine:
         pending, self._pending = self._pending, []
         if overlapped:
             self.metrics.record_overlapped_decode(len(pending))
-        return [self._finish_drain(p, overlapped=overlapped)
-                for p in pending]
+        done = []
+        for p in pending:
+            with self.tracer.region('drain', rid=p.active.request.request_id,
+                                    slot=p.slot, overlapped=overlapped):
+                done.append(self._finish_drain(p))
+        return done
 
     def tick(self, now: Optional[float] = None,
              wall_clock: Optional[bool] = None) -> List[GenerationResult]:
@@ -901,81 +913,91 @@ class ContinuousBatchingEngine:
         is dispatched); an idle tick flushes the stragglers."""
         wall_clock = (now is None) if wall_clock is None else wall_clock
         now = time.perf_counter() - self._wall_t0 if now is None else now
+        with self.tracer.region('tick', tick=self.metrics.ticks) as span:
+            done = self._tick(now, wall_clock, span)
+            span.set(drained=len(done))
+        if self.reporter is not None:
+            self.reporter.maybe_report(engine=self)
+        return done
+
+    def _tick(self, now: float, wall_clock: bool,
+              span) -> List[GenerationResult]:
+        tr = self.tracer
         t_tick0 = time.perf_counter()
-        self._admit(now)
+        with tr.region('admit') as sp:
+            sp.set(admitted=self._admit(now))
         if self.active_count == 0:
             # nothing to step: materialize leftover overlapped decodes
             # (no compute to hide behind, so not counted as overlapped)
             return self._flush_pending(overlapped=False)
-        caching = self.cache_interval > 1
-        refresh_tick = self._phase == 0
-        t = np.zeros(self.slots, np.int32)
-        t_prev = np.full(self.slots, -1, np.int32)
-        guidance = np.zeros(self.slots, np.float32)
-        needs_refresh = np.ones(self.slots, bool)
-        track_exit = False
-        for idx, a in enumerate(self._slot):
-            if a is None:
-                continue
-            t[idx] = a.ts[a.i]
-            t_prev[idx] = a.ts[a.i + 1] if a.i + 1 < len(a.ts) else -1
-            guidance[idx] = a.request.guidance
-            needs_refresh[idx] = ((not a.cache_on) or a.i == 0
-                                  or refresh_tick or a.force_refresh)
-            if a.exit_tol > 0.0 and a.i + 1 >= self.exit_min_steps:
-                track_exit = True
-        plan = plan_tick(
-            [a.request.precision if a is not None else None
-             for a in self._slot],
-            needs_refresh, caching)
-        tick_idx = self.metrics.ticks
-        active_mask = np.zeros(self.slots, bool)
-        for _, _, m in plan:
-            active_mask |= m
-        self.metrics.record_tick(
-            int(active_mask.sum()),
-            full_slots=int((active_mask & needs_refresh).sum()),
-            cached_slots=int((active_mask & ~needs_refresh).sum()))
-        had_cached = self._cached_active() > 0
+        with tr.region('plan') as sp:
+            caching = self.cache_interval > 1
+            refresh_tick = self._phase == 0
+            t = np.zeros(self.slots, np.int32)
+            t_prev = np.full(self.slots, -1, np.int32)
+            guidance = np.zeros(self.slots, np.float32)
+            needs_refresh = np.ones(self.slots, bool)
+            track_exit = False
+            for idx, a in enumerate(self._slot):
+                if a is None:
+                    continue
+                t[idx] = a.ts[a.i]
+                t_prev[idx] = a.ts[a.i + 1] if a.i + 1 < len(a.ts) else -1
+                guidance[idx] = a.request.guidance
+                needs_refresh[idx] = ((not a.cache_on) or a.i == 0
+                                      or refresh_tick or a.force_refresh)
+                if a.exit_tol > 0.0 and a.i + 1 >= self.exit_min_steps:
+                    track_exit = True
+            plan = plan_tick(
+                [a.request.precision if a is not None else None
+                 for a in self._slot],
+                needs_refresh, caching)
+            tick_idx = self.metrics.ticks
+            active_mask = np.zeros(self.slots, bool)
+            for _, _, m in plan:
+                active_mask |= m
+            n_active = int(active_mask.sum())
+            self.metrics.record_tick(
+                n_active,
+                full_slots=int((active_mask & needs_refresh).sum()),
+                cached_slots=int((active_mask & ~needs_refresh).sum()))
+            had_cached = self._cached_active() > 0
+            t_d, tp_d = jnp.asarray(t), jnp.asarray(t_prev)
+            sp.set(entries=len(plan))
+        span.set(active=n_active)
         # one pre-compiled masked step per plan entry — (precision group,
         # refresh|skip) submask; donated latent/x0/cache buffers chain
         # call to call, so slots outside the running submask pass through
         # untouched
-        traced = self.tracer.enabled
         delta_parts = []
-        t_d, tp_d = jnp.asarray(t), jnp.asarray(t_prev)
         for pname, refresh, m in plan:
             g = np.where(m, guidance, 0.0).astype(np.float32)
             guided = self.context is not None and bool(g.any())
-            key = self._tick_key(self._policy_for(pname), tick_idx)
-            m_d, g_d = jnp.asarray(m), jnp.asarray(g)
-            t_step0 = self.tracer.now() if traced else 0.0
-            if caching:
-                step_fn = self._get_cached_step(pname, guided,
-                                                refresh=refresh)
-                if guided:
-                    (self.x, self.x0, d, self._cache_c,
-                     self._cache_u) = step_fn(
-                        self.x, self.x0, self._cache_c, self._cache_u,
-                        t_d, tp_d, m_d, g_d, key, self._unet_w, self._ctx)
+            with tr.region('dispatch', tick=tick_idx, precision=pname,
+                           guided=guided, refresh=refresh,
+                           slots=int(m.sum())):
+                key = self._tick_key(self._policy_for(pname), tick_idx)
+                m_d, g_d = jnp.asarray(m), jnp.asarray(g)
+                if caching:
+                    step_fn = self._get_cached_step(pname, guided,
+                                                    refresh=refresh)
+                    if guided:
+                        (self.x, self.x0, d, self._cache_c,
+                         self._cache_u) = step_fn(
+                            self.x, self.x0, self._cache_c, self._cache_u,
+                            t_d, tp_d, m_d, g_d, key, self._unet_w,
+                            self._ctx)
+                    else:
+                        self.x, self.x0, d, self._cache_c = step_fn(
+                            self.x, self.x0, self._cache_c,
+                            t_d, tp_d, m_d, g_d, key, self._unet_w,
+                            self._ctx)
                 else:
-                    self.x, self.x0, d, self._cache_c = step_fn(
-                        self.x, self.x0, self._cache_c,
-                        t_d, tp_d, m_d, g_d, key, self._unet_w, self._ctx)
-            else:
-                step_fn = self._get_step(pname, guided)
-                self.x, self.x0, d = step_fn(
-                    self.x, self.x0, t_d, tp_d, m_d, g_d, key,
-                    self._unet_w, self._ctx)
+                    step_fn = self._get_step(pname, guided)
+                    self.x, self.x0, d = step_fn(
+                        self.x, self.x0, t_d, tp_d, m_d, g_d, key,
+                        self._unet_w, self._ctx)
             delta_parts.append((m, d))
-            if traced:
-                n_m = int(m.sum())
-                self.tracer.complete(
-                    'step', t_step0, self.tracer.now(), cat='tick',
-                    tick=tick_idx, precision=pname, refresh=refresh,
-                    guided=guided, slots=n_m,
-                    energy_j=self._step_energy_j(pname, refresh,
-                                                 guided) * n_m)
         # decode overlap: decodes dispatched LAST tick materialize now,
         # behind the UNet step(s) just launched above
         done: List[GenerationResult] = self._flush_pending(overlapped=True)
@@ -989,9 +1011,10 @@ class ContinuousBatchingEngine:
         # when some active slot is actually early-exit eligible this tick
         deltas = np.zeros(self.slots, np.float32)
         if track_exit:
-            for m, d in delta_parts:
-                dn = np.asarray(d)
-                deltas[m] = dn[m]
+            with tr.region('exit_sync'):
+                for m, d in delta_parts:
+                    dn = np.asarray(d)
+                    deltas[m] = dn[m]
         for idx, a in enumerate(self._slot):
             if a is None:
                 continue
@@ -1011,13 +1034,18 @@ class ContinuousBatchingEngine:
                     a.exit_streak = 0
                 if a.exit_streak >= a.exit_patience:
                     finished = early = True
-            if finished:
-                p = self._begin_drain(idx, now, wall_clock=wall_clock,
-                                      early=early)
-                if self.overlap_decode:
-                    self._pending.append(p)   # sync behind the next tick
-                else:
-                    done.append(self._finish_drain(p))
+            if not finished:
+                continue
+            if self.overlap_decode:
+                # dispatch only: the image syncs behind the next tick,
+                # in that tick's drain span
+                self._pending.append(self._begin_drain(
+                    idx, now, wall_clock=wall_clock, early=early))
+                continue
+            with tr.region('drain', rid=a.request.request_id, slot=idx,
+                           overlapped=False):
+                done.append(self._finish_drain(self._begin_drain(
+                    idx, now, wall_clock=wall_clock, early=early)))
         if caching and had_cached:
             self._phase = (self._phase + 1) % self.cache_interval
         if self.monitor is not None:
@@ -1029,17 +1057,9 @@ class ContinuousBatchingEngine:
             for dev in range(int(self.mesh.shape['data'])):
                 self.monitor.record(dev, dt)
             self._poll_straggler()
-        if traced:
-            t1 = self.tracer.now()
-            self.tracer.complete(
-                'tick', t1 - (time.perf_counter() - t_tick0), t1,
-                cat='tick', tick=tick_idx,
-                active=int(active_mask.sum()), drained=len(done))
-            self.tracer.counter('occupancy', cat='engine', tick=tick_idx,
-                                active=self.active_count,
-                                queued=len(self.queue))
-        if self.reporter is not None:
-            self.reporter.maybe_report(engine=self)
+        if tr.enabled:
+            tr.counter('occupancy', cat='engine', tick=tick_idx,
+                       active=self.active_count, queued=len(self.queue))
         return done
 
     def run_until_idle(self, now: Optional[float] = None,
@@ -1117,6 +1137,11 @@ class ContinuousBatchingEngine:
                              '(construct with mesh=serving_mesh(...))')
         if n_devices is None and devices is None:
             raise ValueError('pass n_devices or an explicit device list')
+        with self.tracer.region('elastic_resize') as span:
+            return self._resize(n_devices, devices, warm, precisions, span)
+
+    def _resize(self, n_devices, devices, warm, precisions,
+                span) -> List[GenerationResult]:
         flushed = self._flush_pending(overlapped=False)
         from repro.launch.mesh import serving_mesh
         mesh = serving_mesh(n_devices=n_devices, devices=devices)
@@ -1155,9 +1180,8 @@ class ContinuousBatchingEngine:
         self.monitor = StepMonitor(n_hosts=new_ndev)
         self._straggler_flagged = ()
         self.metrics.record_resize(old_ndev, new_ndev)
-        self.tracer.instant('elastic_resize', cat='engine',
-                            old_devices=old_ndev, new_devices=new_ndev,
-                            slots=new_slots, parked=len(self._parked))
+        span.set(old_devices=old_ndev, new_devices=new_ndev,
+                 slots=new_slots, parked=len(self._parked))
         for idx in range(self.slots):
             if not self._parked:
                 break
@@ -1188,35 +1212,32 @@ class ContinuousBatchingEngine:
         t0 = time.perf_counter()
         saved_q, saved_m = self.queue, self.metrics
         saved_probe, saved_tracer = self.quality_probe, self.tracer
-        self.queue, self.metrics = AdmissionQueue(), ServingMetrics()
-        self.quality_probe = 0          # no fp32 references for throwaways
-        self.tracer = NULL_TRACER       # throwaways must not pollute traces
         # enough steps to cross a refresh boundary: compiles refresh+skip
         steps = 1 if self.cache_interval <= 1 else self.cache_interval + 1
-        try:
-            for i, pname in enumerate(precisions):
-                self.submit(GenerationRequest(request_id=-(2 * i + 1),
-                                              seed=0, steps=steps,
-                                              exit_tol=0.0,
-                                              precision=pname), now=0.0)
-                self.run_until_idle(now=0.0)
-                if self.context is not None:
-                    # separately: the guided tick variant
-                    self.submit(GenerationRequest(request_id=-(2 * i + 2),
-                                                  seed=0, steps=steps,
-                                                  guidance=7.5,
-                                                  exit_tol=0.0,
-                                                  precision=pname), now=0.0)
+        with saved_tracer.region('warmup',
+                                 precisions=list(precisions)) as span:
+            self.queue, self.metrics = AdmissionQueue(), ServingMetrics()
+            self.quality_probe = 0      # no fp32 references for throwaways
+            self.tracer = NULL_TRACER   # throwaways must not pollute traces
+            try:
+                for i, pname in enumerate(precisions):
+                    self.submit(GenerationRequest(
+                        request_id=-(2 * i + 1), seed=0, steps=steps,
+                        exit_tol=0.0, precision=pname), now=0.0)
                     self.run_until_idle(now=0.0)
-        finally:
-            self.queue, self.metrics = saved_q, saved_m
-            self.quality_probe, self.tracer = saved_probe, saved_tracer
-        dt = time.perf_counter() - t0
+                    if self.context is not None:
+                        # separately: the guided tick variant
+                        self.submit(GenerationRequest(
+                            request_id=-(2 * i + 2), seed=0, steps=steps,
+                            guidance=7.5, exit_tol=0.0, precision=pname),
+                            now=0.0)
+                        self.run_until_idle(now=0.0)
+            finally:
+                self.queue, self.metrics = saved_q, saved_m
+                self.quality_probe, self.tracer = saved_probe, saved_tracer
+            dt = time.perf_counter() - t0
+            span.set(seconds=dt)
         self.metrics.record_warmup(dt)
-        if self.tracer.enabled:
-            t1 = self.tracer.now()
-            self.tracer.complete('warmup', t1 - dt, t1, cat='engine',
-                                 precisions=list(precisions), seconds=dt)
         trim_cache()    # enforce the persistent-cache size bound, if any
         return dt
 
@@ -1255,6 +1276,12 @@ class ContinuousBatchingEngine:
         if cache_dir is not None:
             from repro.serving.compile_cache import enable_persistent_cache
             enable_persistent_cache(cache_dir)
+        with self.tracer.region('aot_warmup') as span:
+            out = self._aot_compile(precisions)
+            span.set(variants=out['variants'], seconds=out['seconds'])
+        return out
+
+    def _aot_compile(self, precisions) -> Dict[str, object]:
         t0 = time.perf_counter()
         S = jax.ShapeDtypeStruct
         # sharded engines lower against slot-sharded buffer shapes, so
@@ -1280,11 +1307,11 @@ class ContinuousBatchingEngine:
                                           key, *w)
         idx = S((), jnp.int32)
         sample = S(self._sample_shape, jnp.float32)
-        lowered['_init_noise'] = self._init_noise.lower(key)
-        lowered['_place'] = self._place.lower(xs, idx, sample)
-        lowered['_take'] = self._take.lower(xs, idx)
+        lowered['init_noise'] = self._init_noise.lower(key)
+        lowered['place_row'] = self._place.lower(xs, idx, sample)
+        lowered['take_row'] = self._take.lower(xs, idx)
         if self._decode is not None:
-            lowered['_decode'] = self._decode.lower(
+            lowered['vae_decode'] = self._decode.lower(
                 self._vae_w, S((1,) + self._sample_shape, jnp.float32))
 
         def build(item):
@@ -1296,12 +1323,7 @@ class ContinuousBatchingEngine:
             built = list(pool.map(build, lowered.items()))
         trim_cache()    # enforce the persistent-cache size bound, if any
         dt = time.perf_counter() - t0
-        n = len(built)
-        if self.tracer.enabled:
-            t1 = self.tracer.now()
-            self.tracer.complete('aot_warmup', t1 - dt, t1, cat='engine',
-                                 variants=n, seconds=dt)
-        return {'variants': n, 'seconds': dt,
+        return {'variants': len(built), 'seconds': dt,
                 'compile_s': {label: sec for label, _, sec in built},
                 'compiled': {label: exe for label, exe, _ in built}}
 

@@ -34,8 +34,8 @@ def test_cached_engine_zero_recompiles_and_phase(pipe):
                                    quality_probe=0)
     eng.warmup()
     warm = eng.compile_stats()
-    assert warm['_step_refresh'] == 1
-    assert warm['_step_skip'] == 1
+    assert warm['step_fp32_refresh'] == 1
+    assert warm['step_fp32_skip'] == 1
     for i in range(5):
         eng.submit(_req(i, steps=7), now=0.0)
     results = eng.run_until_idle(now=0.0)
@@ -187,9 +187,9 @@ def test_guided_and_quantized_cached_paths(pipe):
                                    cache_interval=2, quality_probe=0)
     eng.warmup(precisions=('fp32', 'w8a8'))
     warm = eng.compile_stats()
-    for label in ('_step_refresh', '_step_skip', '_step_refresh_guided',
-                  '_step_skip_guided', '_step_refresh[w8a8]',
-                  '_step_skip[w8a8]'):
+    for label in ('step_fp32_refresh', 'step_fp32_skip',
+                  'step_fp32_refresh_guided', 'step_fp32_skip_guided',
+                  'step_w8a8_refresh', 'step_w8a8_skip'):
         assert warm[label] == 1, label
     eng.submit(_req(0, steps=5, guidance=2.0), now=0.0)
     eng.submit(_req(1, steps=5, precision='w8a8'), now=0.0)

@@ -1,8 +1,12 @@
 """Observability tests: tracer semantics, zero-cost-when-disabled,
 JSONL/Chrome exporters (strict JSON), Prometheus exposition, the
-snapshot reporter, metrics summary symmetry (p99 + shed breakdown) and
-the engine end-to-end trace <-> metrics reconciliation."""
+snapshot reporter, metrics summary symmetry (p99 + shed breakdown), the
+engine end-to-end trace <-> metrics reconciliation, and the engine's
+spans, program names and UNet scopes as a ``jax.profiler`` trace shows
+them."""
+import glob
 import json
+import os
 
 import jax
 import numpy as np
@@ -10,6 +14,7 @@ import pytest
 
 from repro.diffusion.pipeline import DiffusionPipeline
 from repro.distributed.fault_tolerance import StepMonitor
+from repro.models.autoencoder import VAEConfig
 from repro.models.unet import UNetConfig
 from repro.obs import (NULL_TRACER, SnapshotReporter, Tracer, chrome_trace,
                        read_jsonl, render_exposition, sanitize,
@@ -274,16 +279,23 @@ def test_engine_trace_reconciles_with_metrics(pipe):
     sheds = tr.select('shed')
     assert len(sheds) == engine.queue.shed
     assert all(e.args['reason'] == 'queue_full' for e in sheds)
-    # request-lifecycle instants pair off with the admitted population
-    assert len(tr.select('submit')) == m.submitted
+    # request-lifecycle instants pair off with the admitted population;
+    # every submit call is an engine span, accepted or shed
+    assert len(tr.select('submit', cat='queue')) == m.submitted
+    assert len(tr.spans('submit', cat='engine')) == 6
     assert len(tr.select('slot_assign')) == m.completed
-    assert len(tr.select('decode_dispatch')) == m.completed
-    assert len(tr.select('decode_done')) == m.completed
-    assert len(tr.select('complete')) == m.completed
-    # step spans cover every tick's dispatches and carry energy deltas
-    steps = tr.spans('step')
-    assert steps and all(s.args['energy_j'] > 0 for s in steps)
-    assert sum(s.args['slots'] for s in steps) == m.unet_steps
+    # one drain span per completed request, carrying its id; the request
+    # span keeps the energy the result reports
+    drains = tr.spans('drain')
+    assert sorted(s.rid for s in drains) == sorted(
+        r.request_id for r in results)
+    assert all(s.args['energy_j'] == next(
+        r for r in results if r.request_id == s.rid).energy_j > 0
+        for s in spans)
+    # dispatch spans cover every tick's step calls
+    dispatches = tr.spans('dispatch')
+    assert dispatches and all(s.tick is not None for s in dispatches)
+    assert sum(s.args['slots'] for s in dispatches) == m.unet_steps
     ticks = tr.spans('tick')
     assert len(ticks) == m.ticks
     occ = tr.select('occupancy', ph='C')
@@ -386,3 +398,107 @@ def test_user_on_shed_hook_chains(pipe):
     assert seen == [('rejected', 2)] or seen == [('rejected', 1),
                                                  ('rejected', 2)]
     assert engine.metrics.shed_by_reason.get('queue_full') == len(seen)
+
+
+# ---------------------------------------------------------------------------
+# spans and program names in a jax.profiler trace
+# ---------------------------------------------------------------------------
+
+HOST = '/host:CPU'
+ENGINE_SPANS = ('engine.tick', 'engine.admit', 'engine.plan',
+                'engine.dispatch', 'engine.drain')
+
+
+@pytest.fixture(scope='module')
+def ldm_pipe():
+    """A tiny latent pipeline: every engine program, the decode too."""
+    vae = VAEConfig(img_size=16, in_ch=3, z_ch=4, base_ch=16,
+                    ch_mults=(1, 2), groups=8)
+    unet = UNetConfig('tiny-obs-ldm', img_size=8, in_ch=4, base_ch=32,
+                      ch_mults=(1, 2), n_res_blocks=1, attn_resolutions=(4,),
+                      n_heads=4, timesteps=16, latent=True)
+    return DiffusionPipeline.init(jax.random.PRNGKey(0), unet, vae_cfg=vae)
+
+
+def _profiled_events(log_dir):
+    """(plane, name, start_ns, end_ns, stats) of every event of the
+    trace written under ``log_dir``."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(log_dir, '**', '*.xplane.pb'),
+                            recursive=True))[-1]
+    return [(plane.name, ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for ev in line.events]
+
+
+@pytest.mark.parametrize('recording', [True, False],
+                         ids=['tracer', 'null_tracer'])
+def test_engine_spans_in_profiler_trace(ldm_pipe, tmp_path, recording):
+    """A profiled run puts the engine's phases on the host plane, nested
+    in their tick, and every program under its stable name — with or
+    without a recording tracer; NULL_TRACER still records nothing."""
+    tracer = Tracer() if recording else None
+    engine = ContinuousBatchingEngine(ldm_pipe, slots=2, quality_probe=0,
+                                      tracer=tracer)
+    engine.warmup()
+    before = len(NULL_TRACER)
+    jax.profiler.start_trace(str(tmp_path))
+    for i in range(3):
+        engine.submit(GenerationRequest(request_id=i, seed=i, steps=2),
+                      now=0.0)
+    results = engine.run_until_idle(now=0.0)
+    jax.profiler.stop_trace()
+    evs = _profiled_events(str(tmp_path))
+    host = {}
+    for plane, name, a, b, stats in evs:
+        if plane == HOST and name.startswith('engine.'):
+            host.setdefault(name, []).append((a, b, stats))
+    assert set(ENGINE_SPANS) <= set(host)
+    ticks = host['engine.tick']
+    assert len(ticks) == engine.metrics.ticks
+    assert [t[2]['tick'] for t in ticks] == list(range(len(ticks)))
+    for name in ('engine.drain', 'engine.dispatch'):
+        for a, b, _ in host[name]:
+            assert any(ta <= a and b <= tb for ta, tb, _ in ticks), name
+    drains = host['engine.drain']
+    assert sorted(d[2]['rid'] for d in drains) == sorted(
+        r.request_id for r in results) == [0, 1, 2]
+    assert len(host['engine.submit']) == 3
+    modules = {stats.get('hlo_module') for *_, stats in evs}
+    assert {'jit_vae_decode', 'jit_place_row', 'jit_take_row',
+            'jit_init_noise'} <= modules
+    assert 'jit_step_fp32' in modules
+    assert not any(str(m).startswith('jit__lambda') for m in modules)
+    if recording:
+        assert len(tracer.spans('drain')) == 3
+    else:
+        assert len(NULL_TRACER) == before == 0
+
+
+def test_program_names(pipe):
+    """Every step variant runs as ``jit_step...``; no helper does; the
+    same names key ``compile_stats``."""
+    name = ContinuousBatchingEngine.step_label
+    assert name('fp32', True) == 'step_fp32_guided'
+    assert name('fp32', True, refresh=True) == 'step_fp32_refresh_guided'
+    assert name('w8a8+noise', False, refresh=False) == \
+        'step_w8a8_noise_skip'
+    labels = {name(p, g, r) for p in ('fp32', 'w8a8', 'w8a8+noise')
+              for g in (False, True) for r in (None, False, True)}
+    assert len(labels) == 18 and all(n.startswith('step_') for n in labels)
+    engine = ContinuousBatchingEngine(pipe, slots=2, quality_probe=0)
+    engine.warmup()
+    assert set(engine.compile_stats()) == {'step_fp32', 'init_noise',
+                                           'place_row', 'take_row'}
+
+
+def test_step_hlo_carries_block_scopes(pipe):
+    """The compiled step's op metadata names the UNet block of each op."""
+    engine = ContinuousBatchingEngine(pipe, slots=2, quality_probe=0)
+    text = engine.aot_warmup()['compiled']['step_fp32'].as_text()
+    assert 'HloModule jit_step_fp32,' in text
+    for scope in ('t_embed', 'conv_in', 'resblock', 'attn', 'downsample',
+                  'upsample', 'conv_out'):
+        assert f'/{scope}/' in text, scope
+    # blocks never nest: no op sits in two block scopes
+    assert '/resblock/attn/' not in text and '/attn/resblock/' not in text

@@ -106,7 +106,7 @@ def test_engine_guided_slots_match_pipeline_guidance():
     engine = ContinuousBatchingEngine(p, slots=2, context=ctx)
     engine.warmup()
     warm = engine.compile_stats()
-    assert warm.get('_step_guided', 0) == 1
+    assert warm.get('step_fp32_guided', 0) == 1
     reqs = [GenerationRequest(0, seed=11, steps=3, guidance=2.5),
             GenerationRequest(1, seed=12, steps=3)]
     results = _drive(engine, {0: reqs})
@@ -291,9 +291,9 @@ def test_mixed_precision_ticks_zero_recompiles(pipe):
     engine = ContinuousBatchingEngine(pipe, slots=3, quality_probe=0)
     engine.warmup(precisions=('fp32', 'w8a8', 'w8a8+noise'))
     warm = engine.compile_stats()
-    assert warm['_step'] == 1
-    assert warm['_step[w8a8]'] == 1
-    assert warm['_step[w8a8+noise]'] == 1
+    assert warm['step_fp32'] == 1
+    assert warm['step_w8a8'] == 1
+    assert warm['step_w8a8_noise'] == 1
     mix = ['fp32', 'w8a8', 'w8a8+noise']
     reqs = [GenerationRequest(i, seed=60 + i, steps=2 + (i % 3),
                               precision=mix[i % 3]) for i in range(6)]
@@ -405,7 +405,7 @@ def test_steps_take_weights_as_arguments(pipe):
     weights = jax.tree_util.tree_leaves((p.unet_params, p.vae_params))
     smallest = min(int(w.size) for w in weights if w.ndim >= 2)
     info = engine.aot_warmup(precisions=('fp32', 'w8a8'))
-    assert {'_step', '_step[w8a8]', '_decode'} <= set(info['compiled'])
+    assert {'step_fp32', 'step_w8a8', 'vae_decode'} <= set(info['compiled'])
     for label, exe in info['compiled'].items():
         for dims in re.findall(r'= f32\[([\d,]+)\]\S* constant\(',
                                exe.as_text()):
