@@ -17,12 +17,13 @@ import jax.numpy as jnp
 
 from repro.core.quantization import quantize, quantize_per_channel
 from repro.kernels import ref as _ref
+from repro.kernels.flash_attention import blocks as flash_blocks
 from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.fused_gn_swish import fused_gn_swish_kernel
 from repro.kernels.w8a8_matmul import w8a8_matmul_kernel
 
 
-def _mode() -> str:
+def kernel_mode() -> str:
     """'pallas' on TPU, 'xla' elsewhere, or forced via REPRO_KERNELS."""
     forced = os.environ.get('REPRO_KERNELS')
     if forced:
@@ -52,7 +53,7 @@ def w8a8_matmul(x: jax.Array, w, *, mode: str | None = None) -> jax.Array:
     output channel here unless already a QTensor (serve-time prequant).
     """
     from repro.core.quantization import QTensor
-    mode = mode or _mode()
+    mode = mode or kernel_mode()
     lead = x.shape[:-1]
     K = x.shape[-1]
     N = w.shape[-1]
@@ -82,38 +83,32 @@ def w8a8_matmul(x: jax.Array, w, *, mode: str | None = None) -> jax.Array:
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, scale: float | None = None,
                     mode: str | None = None) -> jax.Array:
-    """q (B, H, S, d), k/v (B, H, T, d) -> (B, H, S, d)."""
-    mode = mode or _mode()
+    """q (B, H, S, d), k/v (B, H, T, d) -> (B, H, S, d), float32 from the
+    kernel.  The MXU multiplies in q/k/v's own dtype.  The kernel takes the
+    heads side by side on the lanes, (B, S, H*d): a caller holding
+    (B, S, H, d) and transposing to this signature's layout has both
+    transposes cancelled by XLA."""
+    from repro.core.lse_softmax import streaming_attention_ref
+    mode = mode or kernel_mode()
     B, H, S, d = q.shape
     T = k.shape[2]
     if scale is None:
         scale = d ** -0.5
     if mode == 'xla':
-        from repro.core.lse_softmax import streaming_attention_ref
         return streaming_attention_ref(q, k, v, causal=causal, scale=scale)
-    qf = q.reshape(B * H, S, d)
-    kf = k.reshape(B * H, T, d)
-    vf = v.reshape(B * H, T, d)
-    bq = min(128, S)
-    bk = min(128, T)
-    q_p = _pad_to(qf, 1, bq)
-    k_p = _pad_to(kf, 1, bk)
-    v_p = _pad_to(vf, 1, bk)
-    if k_p.shape[1] != T:
-        # padded KV rows must not contribute: causal masking handles q-side
-        # padding; for kv-side padding use an additive -inf via a huge
-        # negative key? Simplest correct: mask by zero-value + min-score:
-        # set padded K rows to produce -inf scores by making them equal to
-        # a large negative multiple of q... safer: fall back to masking via
-        # explicit score mask is not in-kernel; instead pad K with -1e4 *
-        # unit vectors is fragile -> use oracle path for ragged T.
-        if not causal:
-            from repro.core.lse_softmax import streaming_attention_ref
-            return streaming_attention_ref(q, k, v, causal=False, scale=scale)
+    qp, kp, vp = (x.transpose(0, 2, 1, 3).reshape(B, x.shape[2], H * d)
+                  for x in (q, k, v))
+    bb, hg, bq, bk = flash_blocks(B, S, T, H, d, q.dtype.itemsize, causal)
+    qp = _pad_to(qp, 1, bq)
+    kp = _pad_to(kp, 1, bk)
+    vp = _pad_to(vp, 1, bk)
+    if kp.shape[1] != T and not causal:
+        # ragged T runs the oracle; the UNet never sends it here
+        return streaming_attention_ref(q, k, v, causal=False, scale=scale)
     out = flash_attention_kernel(
-        q_p, k_p, v_p, causal=causal, scale=scale, bq=bq, bk=bk,
-        interpret=(mode == 'interpret'))
-    return out[:, :S, :].reshape(B, H, S, d)
+        qp, kp, vp, heads=H, causal=causal, scale=scale, bb=bb, hg=hg,
+        bq=bq, bk=bk, interpret=(mode == 'interpret'))
+    return out[:, :S].reshape(B, S, H, d).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -122,7 +117,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def fused_gn_swish(x: jax.Array, scale: jax.Array, bias: jax.Array, *,
                    groups: int = 32, mode: str | None = None) -> jax.Array:
-    mode = mode or _mode()
+    mode = mode or kernel_mode()
     C = x.shape[-1]
     g = min(groups, C)
     while C % g:

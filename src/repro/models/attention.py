@@ -124,7 +124,7 @@ def flash_core(q, k, v, *, causal):
     out = kops.flash_attention(
         q.transpose(0, 2, 1, 3), kr.transpose(0, 2, 1, 3),
         vr.transpose(0, 2, 1, 3), causal=causal)
-    return out.transpose(0, 2, 1, 3)
+    return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -137,11 +137,34 @@ def init_attn_block(key, ch: int, n_heads: int,
     return p
 
 
+#: self-attention whose length is a whole number of these 128-lane blocks
+#: runs as the flash kernel; the shorter calls' scores are a few MB
+FLASH_TOKENS = 128
+
+
 def _mha(q, k, v, n_heads: int, quant_proj=None) -> jax.Array:
-    """q (B, S, C), k/v (B, T, C) -> (B, S, C) via LSE softmax (C2)."""
+    """q (B, S, C), k/v (B, T, C) -> (B, S, C) via LSE softmax (C2).
+
+    Off the ``xla`` kernel mode, self-attention of a multiple of
+    ``FLASH_TOKENS`` tokens, each longer than a head is wide (T > C / h),
+    runs as the Pallas flash kernel on bfloat16 q/k/v, so its (B, h, S, S)
+    scores never reach HBM.  Every other call is the einsum, whose float32
+    operands the TPU's default precision also multiplies as bfloat16; at
+    T <= C / h its two passes over the scores cost no more than the
+    kernel's float32 output."""
+    from repro.kernels import ops as kops
     B, S, C = q.shape
     T = k.shape[1]
     hd = C // n_heads
+    if (S == T and T % FLASH_TOKENS == 0 and T > hd
+            and kops.kernel_mode() != 'xla'):
+        # cast before the head split, so (B, S, C) stays the only layout
+        def heads(x):
+            return x.astype(jnp.bfloat16).reshape(
+                B, S, n_heads, hd).transpose(0, 2, 1, 3)
+        o = kops.flash_attention(heads(q.astype(jnp.float32) * hd ** -0.5),
+                                 heads(k), heads(v), scale=1.0)
+        return o.transpose(0, 2, 1, 3).reshape(B, S, C).astype(q.dtype)
     qh = q.reshape(B, S, n_heads, hd).astype(jnp.float32) * hd ** -0.5
     kh = k.reshape(B, T, n_heads, hd).astype(jnp.float32)
     vh = v.reshape(B, T, n_heads, hd).astype(jnp.float32)

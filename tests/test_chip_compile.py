@@ -2,8 +2,9 @@
 widths without a chip attached, plus interpret-mode checks of the fused
 GroupNorm+swish at its channels-per-group.
 
-The kernels are called with ``interpret=False`` directly: here ``ops``
-takes its CPU branch.  The topology is described only inside the ``topo``
+The kernels are called with ``interpret=False`` directly, or through
+``ops`` with ``mode='pallas'``: left to itself, ``ops`` takes its CPU
+branch here.  The topology is described only inside the ``topo``
 fixture (never at import), and every test that needs it lives in this one
 file, so under pytest-xdist exactly one worker loads the TPU compiler.
 """
@@ -13,7 +14,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.fused_gn_swish import fused_gn_swish_kernel
 from repro.kernels.w8a8_matmul import w8a8_matmul_kernel
 
@@ -76,6 +77,24 @@ def test_w8a8_matmul_compiles_for_v5e(one_chip, no_compile_cache):
             xq, xs, wq, ws, bm=128),
         ((M, K), jnp.int8), ((M, 1), jnp.float32), ((K, N), jnp.int8),
         ((1, N), jnp.float32))
+    assert 'tpu_custom_call' in exe.as_text()
+
+
+@pytest.mark.parametrize('shape', [
+    (16, 8, 1024, 85),      # t2i_unet_860m 32x32 self-attention, 16 slots
+    (16, 8, 256, 170),      # t2i_unet_860m 16x16
+    (2048, 1, 256, 256),    # ddpm_cifar10_ch128 16x16, 2048 slots
+])
+def test_flash_attention_compiles_for_v5e(one_chip, no_compile_cache,
+                                          shape):
+    """The cells' self-attention shapes, (B, heads, S, d) with bf16 q/k/v
+    as ``models/unet._mha`` makes them (it keeps DDPM's, where T = d, on
+    the einsum): the blocks ``ops`` chooses lower for the chip and fit
+    its VMEM."""
+    exe = _compile(
+        one_chip, lambda q, k, v: ops.flash_attention(q, k, v, scale=1.0,
+                                                      mode='pallas'),
+        *[(shape, jnp.bfloat16)] * 3)
     assert 'tpu_custom_call' in exe.as_text()
 
 

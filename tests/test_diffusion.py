@@ -69,6 +69,42 @@ def test_unet_sparse_dataflow_equivalence():
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+@pytest.mark.parametrize('base_ch,n_heads,context_dim,calls', [
+    (32, 2, 24, 3),       # 256 tokens, heads 16 wide: the kernel
+    (256, 1, None, 0),    # 256 tokens, one head 256 wide: the einsum
+])
+def test_unet_self_attention_dispatches_to_flash_kernel(
+        monkeypatch, base_ch, n_heads, context_dim, calls):
+    """Off ``xla`` mode, exactly the self-attention calls of 256 tokens
+    (16x16: one down block, two up blocks) whose heads are narrower than
+    that reach the flash kernel; the 8x8 mid-block's 64 tokens, every
+    7-token cross-attention, and heads as wide as the sequence (the DDPM
+    shape) stay on the einsum.  The output matches the ``xla``-mode UNet
+    to within 2^-8 relative L2, one bfloat16 rounding of the attention
+    operands (the CPU einsum multiplies in float32)."""
+    import re
+    cfg = UNetConfig('tiny_flash', img_size=16, in_ch=4, base_ch=base_ch,
+                     ch_mults=(1, 2), n_res_blocks=1, attn_resolutions=(16,),
+                     n_heads=n_heads, context_dim=context_dim, groups=8,
+                     timesteps=16)
+    p = init_unet(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16, 4))
+    t = jnp.array([3, 9])
+    ctx = None if context_dim is None else jax.random.normal(
+        jax.random.PRNGKey(2), (2, 7, context_dim))
+    out = {}
+    for mode in ('interpret', 'xla'):
+        monkeypatch.setenv('REPRO_KERNELS', mode)
+        step = jax.jit(lambda p, x, t, c: unet_apply(p, cfg, x, t, c))
+        text = step.lower(p, x, t, ctx).as_text()
+        found = len(re.findall(r'call @flash_attention_kernel', text))
+        assert found == (calls if mode == 'interpret' else 0), (mode, found)
+        out[mode] = step(p, x, t, ctx)
+    rel = float(jnp.linalg.norm(out['interpret'] - out['xla'])
+                / jnp.linalg.norm(out['xla']))
+    assert rel < 2.0 ** -8, rel
+
+
 def test_ddpm_training_reduces_loss():
     sched = linear_schedule(TINY.timesteps)
     p = init_unet(jax.random.PRNGKey(0), TINY)

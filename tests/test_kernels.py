@@ -72,6 +72,33 @@ def test_flash_attention_vs_ref(S, T, d, causal):
                                np.asarray(exp), atol=2e-5)
 
 
+@pytest.mark.parametrize('B,H,S,d', [
+    (1, 2, 128, 85), (2, 2, 256, 85), (2, 1, 256, 170), (1, 4, 128, 170),
+    (1, 2, 256, 256), (3, 1, 128, 256),
+])
+def test_flash_attention_bf16_operands(B, H, S, d):
+    """The UNet's call: q pre-scaled in f32 then q/k/v rounded to bf16,
+    ``scale=1``, at the cells' head dims (85, 170, 256), 2-4 batch*heads.
+    The oracle runs f32 arithmetic on the same rounded operands, so the
+    scores agree to f32 rounding; what differs is ``p = exp(s - m)``
+    entering PV as bf16.  Rounding each p_j by a relative 2^-9 moves
+    sum_j p_j v_j / l by at most 2^-9 * sum_j p_j |v_j| / l <= 2^-9 *
+    max|v| (l sums the unrounded f32 p), hence the tolerance, with 1e-5
+    for f32 summation order."""
+    q = (_arr((B, H, S, d)) * d ** -0.5).astype(jnp.bfloat16)
+    k = _arr((B, H, S, d)).astype(jnp.bfloat16)
+    v = _arr((B, H, S, d)).astype(jnp.bfloat16)
+    out = ops.flash_attention(q, k, v, scale=1.0, mode='interpret')
+    assert out.dtype == jnp.float32
+    exp = ref.attention_ref(
+        *(x.reshape(B * H, S, d).astype(jnp.float32) for x in (q, k, v)),
+        scale=1.0)
+    vmax = float(jnp.max(jnp.abs(v.astype(jnp.float32))))
+    np.testing.assert_allclose(np.asarray(out).reshape(B * H, S, d),
+                               np.asarray(exp), rtol=0,
+                               atol=2.0 ** -9 * vmax + 1e-5)
+
+
 def test_flash_equals_streaming_ref():
     """Kernel == the executable rendering of paper Eq. 4 streaming."""
     from repro.core.lse_softmax import streaming_attention_ref
