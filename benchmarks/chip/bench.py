@@ -10,8 +10,10 @@ runs in this one process.  A cell is an entry of ``BENCHMARK.json``'s
 mix (``traffic/<name>.json``).  The run:
 
 1. draws the weights from the seed on the device in one compiled program
-   (``weights.py``) and builds the engine the way ``serve --diffusion``
-   does (``launch.serve.build_engine(pipe=..., slots=...)``);
+   (``weights.py``, in the layout of the configuration's model module,
+   ``models.py``) and builds the engine the way ``serve --diffusion``
+   does (``launch.serve.build_engine(pipe=..., slots=...)``), over the
+   noise schedule the configuration states;
 2. warms up with one throwaway request of the cell's own kind through
    ``submit`` / ``tick``, then runs the mix (``traffic.py``) through a
    ramp of about one turnover so the slots hold requests of mixed ages;
@@ -22,9 +24,9 @@ mix (``traffic/<name>.json``).  The run:
    requests are fixed (``settled``), or every one of them has finished;
 4. reads ``peak_bytes_in_use``, frees the engine and compares a sample
    of the images served since the window opened, drawn from the seed
-   with the longest request in it, with the plain reference
-   (``reference.py``) run over the same requests; ``limits/<cell>.json``
-   holds the limit;
+   with the longest request in it, with the plain reference of the
+   configuration's model module run over the same requests;
+   ``limits/<cell>.json`` holds the limit;
 5. prints the metrics: with ``--trace 0`` the cell's end-to-end ones,
    with ``--trace 1`` (the window under the profiler) its per-layer ones,
    each read by ``metrics/<name>.py``.
@@ -61,6 +63,7 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(HERE, 'metrics'))
 
+import models  # noqa: E402
 from _common import latencies, percentile, service_times  # noqa: E402
 
 #: host annotations the benchmark wraps its own calls in (read by the
@@ -93,6 +96,12 @@ def cell_spec(name: str, root: str = ROOT):
         raise SystemExit(f'unknown workload {name!r}: {sorted(cells)}')
     cell = cells[name]
     conf = {c['name']: c for c in bench['configs']}[cell['config']]
+    cfg_path = os.path.join(root, conf['file'])
+    cfg = load_json(cfg_path)
+    if 'model' not in cfg:
+        raise SystemExit(f'{cfg_path}: no "model": name the module of '
+                         'this directory that holds its model')
+    models.of(cfg)
     e2e = [m for m in bench['end_to_end']
            if name in m.get('workloads', [name])]
     reported = {m['name'] for m in e2e}
@@ -102,7 +111,7 @@ def cell_spec(name: str, root: str = ROOT):
     return {
         'name': name,
         'chips': int(cell['chips']),
-        'config': load_json(os.path.join(root, conf['file'])),
+        'config': cfg,
         'traffic': load_json(os.path.join(HERE, 'traffic',
                                           cell['traffic'] + '.json')),
         'limits': load_json(os.path.join(HERE, 'limits', name + '.json')),
@@ -191,10 +200,12 @@ def derive(seed: int, n: int):
 # ---------------------------------------------------------------------------
 
 def build_engine(cfg, params, ctx_seed: int):
-    """The served engine over the benchmark's weights."""
+    """The served engine over the benchmark's weights, with the noise
+    schedule of the configuration's ``schedule``: the program's
+    ``<kind>_schedule(timesteps, **the other keys)``."""
     import jax
+    from repro.diffusion import schedule as schedules
     from repro.diffusion.pipeline import DiffusionPipeline
-    from repro.diffusion.schedule import linear_schedule
     from repro.launch import serve
     from repro.models.autoencoder import VAEConfig
     from repro.models.unet import UNetConfig
@@ -204,9 +215,14 @@ def build_engine(cfg, params, ctx_seed: int):
         return cls(**{k: tuple(v) if isinstance(v, list) else v
                       for k, v in d.items() if k in names}, **kw)
     u, v = cfg['unet'], cfg.get('vae')
+    sched = dict(cfg['schedule'])
+    kind = sched.pop('kind')
+    make = getattr(schedules, kind + '_schedule', None)
+    if make is None:
+        raise ValueError(f'repro.diffusion.schedule has no {kind}_schedule')
     pipe = DiffusionPipeline(
         frozen(u, UNetConfig, name=cfg['name']), params['unet'],
-        linear_schedule(u['timesteps']),
+        make(u['timesteps'], **sched),
         None if v is None else frozen(v, VAEConfig), params['vae'])
     engine = serve.build_engine(pipe=pipe, slots=int(cfg['slots']),
                                 seed=ctx_seed)
@@ -406,18 +422,19 @@ def rel_l2(a, b) -> float:
 
 def compare(cfg, params, sample, guidance, ctx_seed, controls=()):
     """Per request, the relative L2 distance between the served image and
-    the reference image of the same request; and for each operand
-    rounding named in ``controls``, the control's distances (its images
-    computed from the same weights with that rounding)."""
-    import reference
+    the reference image of the same request (the configuration's model
+    module's ``sample``); and for each operand rounding named in
+    ``controls``, the control's distances (its images computed from the
+    same weights with that rounding)."""
+    model = models.of(cfg)
     u = cfg['unet']
     ctx = None
     if u.get('context_dim') is not None:
-        ctx = reference.context_rows(ctx_seed, int(cfg['context_tokens']),
-                                     u['context_dim'])
+        ctx = model.context_rows(ctx_seed, int(cfg['context_tokens']),
+                                 u['context_dim'])
 
     def images(operands):
-        return {rec.rid: reference.sample(
+        return {rec.rid: model.sample(
             params['unet'], params['vae'], cfg, rec.seed, rec.steps,
             guidance, ctx, operands=operands) for rec in sample}
     ref = images(None)
@@ -449,8 +466,8 @@ def run(spec, seed: int, seconds: float, trace: bool, *,
         controls=()):
     """One run of a cell; returns the result dict (or None when the
     device check fails).  ``controls`` names operand roundings of the
-    reference (``reference.OPERANDS``) to run over the sample as well,
-    each a control reading."""
+    reference (the model module's ``OPERANDS``) to run over the sample as
+    well, each a control reading."""
     if require_chip:
         devs = check_device(spec['chips'])
         if devs is None:
@@ -541,7 +558,8 @@ def run(spec, seed: int, seconds: float, trace: bool, *,
     correct = bool(sample) and not bad and worst <= limit
 
     ctx = {
-        'spec': spec, 'config': cfg, 'traffic': mix, 'seconds': seconds,
+        'spec': spec, 'config': cfg, 'model': models.of(cfg),
+        'traffic': mix, 'seconds': seconds,
         'window': (t_open, t_close), 'records': recs, 'finished': finished,
         'due_in_window': due_in, 'ticks': loop.window_ticks,
         'setup_s': state['setup_s'], 'peak_bytes': peak, 'trace': red,

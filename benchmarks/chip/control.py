@@ -7,10 +7,10 @@ own load.
         --seconds <s> [--controls int8,fp8]
 
 The runs share one process (set-up is long); each prints one JSON line
-with the compared number and each control's (``reference.OPERANDS``;
-default: the limits file's ``control``).  The benchmark's own runs do not
-run the control: this is how the limits in ``limits/<cell>.json`` were
-read.
+with the compared number and each control's (an operand rounding of the
+configuration's model module, its ``OPERANDS``; default: the limits
+file's ``control``).  The benchmark's own runs do not run the control:
+this is how the limits in ``limits/<cell>.json`` were read.
 """
 from __future__ import annotations
 
@@ -24,6 +24,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
 import bench  # noqa: E402
+import models  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -35,6 +36,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     spec = bench.cell_spec(args.workload)
     controls = (args.controls or spec['limits']['control']).split(',')
+    known = models.of(spec['config']).OPERANDS
+    unknown = [c for c in controls if c not in known]
+    if unknown:
+        ap.error(f'no control {", ".join(unknown)}: the model module has '
+                 f'{", ".join(known)}')
     for seed in (int(s) for s in args.seeds.split(',')):
         out = bench.run(spec, seed, args.seconds, False, controls=controls)
         if out is None:

@@ -15,6 +15,27 @@ float32 matmuls run on a TPU.
 The parameter layout (``unet_shapes`` / ``vae_decoder_shapes``) is the
 nested-dict layout the served UNet reads; ``weights.py`` fills it from
 the seed, and this module reads the same arrays by name.
+
+**The model interface.**  A configuration file names its model module in
+``"model"`` (a module of this directory; ``models.of`` looks it up), and
+the benchmark reaches the model through nothing else.  This module is the
+one for the repo's UNet block; a module for another architecture has the
+same names:
+
+- ``shapes(cfg)``: ``{'unet': tree, 'vae': tree or None}``, nested dicts
+  whose leaves are shape tuples; ``weights.py`` draws each leaf by its
+  key (``w``, ``b``, ``scale``, anything else).
+- ``sample(unet_p, vae_p, cfg, seed, steps, guidance, context,
+  operands=None)``: the image one request should come out as, a NumPy
+  array; ``operands`` names a control rounding of ``OPERANDS``.  Another
+  module passes its own UNet, decoder and alpha-bars to ``ddim_sample``,
+  the loop this one uses.
+- ``context_rows(seed, tokens, dim)``: the conditioning every slot is
+  served with.
+- ``OPERANDS``: the control's operand roundings, by name.
+- ``image_flops(cfg, steps, guided)``: useful FLOPs of one served image.
+- ``gn_swish_calls(cfg, batch, context=True)``: the (N, H, W, C) shape
+  of every fused GroupNorm+swish call in one UNet evaluation.
 """
 from __future__ import annotations
 
@@ -25,6 +46,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import flops
 
 F32 = jnp.float32
 
@@ -141,8 +164,12 @@ def vae_decoder_shapes(v):
     return p
 
 
-def is_shape(x):
-    return isinstance(x, tuple)
+def shapes(cfg):
+    """{'unet': ..., 'vae': ...} parameter shape trees; 'vae' is None
+    without a VAE."""
+    vae = cfg.get('vae')
+    return {'unet': unet_shapes(cfg['unet']),
+            'vae': None if vae is None else vae_decoder_shapes(vae)}
 
 
 # ---------------------------------------------------------------------------
@@ -314,9 +341,19 @@ def vae_decode(p, v, z):
 # sampling
 # ---------------------------------------------------------------------------
 
-def alpha_bars(T, beta_0=1e-4, beta_T=0.02):
+def alpha_bars(T, beta_0, beta_T):
     """Cumulative products of the linear schedule, in float64."""
     return np.cumprod(1.0 - np.linspace(beta_0, beta_T, T, dtype=np.float64))
+
+
+def linear_alpha_bars(cfg):
+    """``alpha_bars`` of the configuration's ``schedule``, which has to be
+    ``linear``."""
+    s = cfg['schedule']
+    if s['kind'] != 'linear':
+        raise ValueError(f"the reference has no {s['kind']!r} schedule, "
+                         "only 'linear'")
+    return alpha_bars(cfg['unet']['timesteps'], s['beta_0'], s['beta_T'])
 
 
 def ddim_timesteps(T, steps):
@@ -332,24 +369,26 @@ def initial_noise(seed, shape):
                              shape, F32)
 
 
-@functools.partial(jax.jit, static_argnames=('u', 'guided', 'operands'))
-def _ddim_step(p, x, t, c, context, guidance, *, u, guided, operands):
+@functools.partial(jax.jit,
+                   static_argnames=('unet_fn', 'u', 'guided', 'operands'))
+def _ddim_step(p, x, t, c, context, guidance, *, unet_fn, u, guided,
+               operands):
     """One DDIM update; ``c`` = (sqrt(ab_t), sqrt(1-ab_t), sqrt(ab_prev),
     sqrt(1-ab_prev))."""
     tb = jnp.reshape(t, (1,))
     with _operands(operands):
-        eps = unet(p, u, x, tb, context)
+        eps = unet_fn(p, u, x, tb, context)
         if guided:
-            e_unc = unet(p, u, x, tb, None)
+            e_unc = unet_fn(p, u, x, tb, None)
             eps = e_unc + guidance * (eps - e_unc)
     x0 = (x - c[1] * eps) / c[0]
     return c[2] * x0 + c[3] * eps
 
 
-@functools.partial(jax.jit, static_argnames=('v', 'operands'))
-def _decode(p, z, *, v, operands):
+@functools.partial(jax.jit, static_argnames=('decode_fn', 'v', 'operands'))
+def _decode(p, z, *, decode_fn, v, operands):
     with _operands(operands):
-        return vae_decode(p, v, z)
+        return decode_fn(p, v, z)
 
 
 def _hashable(cfg):
@@ -365,16 +404,15 @@ class _Frozen(dict):
         return isinstance(other, dict) and dict.__eq__(self, other)
 
 
-def sample(unet_p, vae_p, cfg, seed, steps, guidance, context,
-           operands=None):
-    """The image one request should come out as: DDIM from the request's
-    noise over ``steps`` steps, guided when ``guidance > 0`` and a context
-    is given, decoded when the configuration has a VAE.  ``operands``
-    names the control's operand rounding (``OPERANDS``); None is the
-    reference."""
+def ddim_sample(unet_fn, decode_fn, ab, unet_p, vae_p, cfg, seed, steps,
+                guidance, context, operands=None):
+    """``sample`` for any model: DDIM over the alpha-bars ``ab`` with
+    ``unet_fn(p, u, x, t, context)`` as the noise prediction and
+    ``decode_fn(p, v, z)`` as the decoder (used when the configuration
+    has a VAE).  Both are traced with the control's rounding in force, so
+    a model built from this module's layers gets its control for free."""
     u = _hashable(cfg['unet'])
     T = u['timesteps']
-    ab = alpha_bars(T)
     ts = ddim_timesteps(T, steps)
     shape = (1, u['img_size'], u['img_size'], u['in_ch'])
     x = initial_noise(seed, shape)
@@ -384,11 +422,24 @@ def sample(unet_p, vae_p, cfg, seed, steps, guidance, context,
         ab_prev = ab[ts[i + 1]] if i + 1 < len(ts) else 1.0
         coefs = jnp.asarray([math.sqrt(ab[t]), math.sqrt(1 - ab[t]),
                              math.sqrt(ab_prev), math.sqrt(1 - ab_prev)], F32)
-        x = _ddim_step(unet_p, x, jnp.int32(t), coefs, context, g, u=u,
-                       guided=guided, operands=operands)
+        x = _ddim_step(unet_p, x, jnp.int32(t), coefs, context, g,
+                       unet_fn=unet_fn, u=u, guided=guided,
+                       operands=operands)
     if cfg.get('vae') is not None:
-        x = _decode(vae_p, x, v=_hashable(cfg['vae']), operands=operands)
+        x = _decode(vae_p, x, decode_fn=decode_fn,
+                    v=_hashable(cfg['vae']), operands=operands)
     return np.asarray(x[0])
+
+
+def sample(unet_p, vae_p, cfg, seed, steps, guidance, context,
+           operands=None):
+    """The image one request should come out as: DDIM from the request's
+    noise over ``steps`` steps, guided when ``guidance > 0`` and a context
+    is given, decoded when the configuration has a VAE.  ``operands``
+    names the control's operand rounding (``OPERANDS``); None is the
+    reference."""
+    return ddim_sample(unet, vae_decode, linear_alpha_bars(cfg), unet_p,
+                       vae_p, cfg, seed, steps, guidance, context, operands)
 
 
 def context_rows(seed, tokens, dim):
@@ -396,3 +447,11 @@ def context_rows(seed, tokens, dim):
     ``(1, tokens, dim)`` normal draw from ``PRNGKey(seed + 1)``."""
     return jax.random.normal(jax.random.PRNGKey(seed + 1), (1, tokens, dim),
                              F32)
+
+
+def image_flops(cfg, steps, guided):
+    return flops.image(cfg, steps, guided)
+
+
+def gn_swish_calls(cfg, batch, context=True):
+    return flops.gn_swish_calls(cfg['unet'], batch, context)

@@ -1,5 +1,6 @@
-"""Seeded weights for a configuration, drawn on the device in one compiled
-program, in float32 (the type they are served in).
+"""Seeded weights for a configuration, in the layout its model module
+gives (``shapes``), drawn on the device in one compiled program, in
+float32 (the type they are served in).
 
 The draws use the ``rbg`` generator (XLA's RngBitGenerator), one
 operation per leaf, which compiles much faster than threefry's unrolled
@@ -17,7 +18,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-import reference
+import models
 
 
 def _draw(key, name, shape):
@@ -36,12 +37,14 @@ def _draw_all(key, names, dims):
     return [_draw(k, n, s) for k, n, s in zip(keys, names, dims)]
 
 
+def _is_shape(x):
+    return isinstance(x, tuple)
+
+
 def shapes(cfg):
-    """{'unet': ..., 'vae': ...} parameter shape trees; 'vae' is None
-    without a VAE."""
-    vae = cfg.get('vae')
-    return {'unet': reference.unet_shapes(cfg['unet']),
-            'vae': None if vae is None else reference.vae_decoder_shapes(vae)}
+    """{'unet': ..., 'vae': ...} parameter shape trees of the
+    configuration's model; 'vae' is None without a VAE."""
+    return models.of(cfg).shapes(cfg)
 
 
 def make(cfg, seed: int):
@@ -49,7 +52,7 @@ def make(cfg, seed: int):
     ``seed``."""
     tree = shapes(cfg)
     leaves, treedef = jax.tree_util.tree_flatten_with_path(
-        tree, is_leaf=reference.is_shape)
+        tree, is_leaf=_is_shape)
     names = tuple(str(path[-1].key) for path, _ in leaves)
     dims = tuple(s for _, s in leaves)
     arrays = _draw_all(jax.random.key(seed, impl='rbg'), names, dims)
@@ -58,4 +61,4 @@ def make(cfg, seed: int):
 
 def count(tree) -> int:
     return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
-        tree, is_leaf=reference.is_shape))
+        tree, is_leaf=_is_shape))
